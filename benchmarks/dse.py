@@ -152,6 +152,9 @@ def main(json_out: bool = False, kernels=None, workers: int = 1,
 
 
 if __name__ == "__main__":
+    from repro.core.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--json", action="store_true", help="emit payload as JSON")
     ap.add_argument("--kernels", default=None,

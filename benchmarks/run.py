@@ -125,4 +125,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.core.compile_cache import use_compile_cache
+
+    use_compile_cache()
     raise SystemExit(main())

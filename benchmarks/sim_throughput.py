@@ -8,9 +8,8 @@ stimulus batch:
     simulation per stimulus vector (the verification path before this
     benchmark's subject existed);
   * ``codegen.sim.RTLSimulator`` — the batched cycle-accurate interpreter
-    (jax scan+vmap when available, vectorized numpy otherwise), timed after
-    a warm-up run so jit compilation is amortized the way a fuzzing loop
-    amortizes it.
+    (jax scan+vmap on JAX's default device), timed after a warm-up run so
+    jit compilation is amortized the way a fuzzing loop amortizes it.
 
 Writes ``artifacts/bench/BENCH_sim_throughput.json`` and exits nonzero (via
 ``RuntimeError`` -> ``benchmarks/run.py``) when the speed-up floor is
@@ -117,4 +116,7 @@ def main(argv=None, profile: bool = False) -> dict:
 
 
 if __name__ == "__main__":
+    from repro.core.compile_cache import use_compile_cache
+
+    use_compile_cache()
     main()

@@ -8,9 +8,10 @@ from the paper's FPGA world to TPU:
   * the FPGA binding emits Verilog (shift registers, FSMs) + a resource
     estimate under the Table-5 cost model;
   * the TPU binding emits a ``pl.pallas_call`` whose grid realises the
-    pipelined schedule and whose VMEM scratch realises the register window —
-    then a retiming error is introduced and the schedule verifier rejects it
-    BEFORE any lowering (paper Fig. 2's class of bug).
+    pipelined schedule and whose SMEM scratch realises the register window
+    (interpreted here, so the example runs on any host) — then a retiming
+    error is introduced and the schedule verifier rejects it BEFORE any
+    lowering (paper Fig. 2's class of bug).
 
     PYTHONPATH=src python examples/hir_to_pallas.py
 """
@@ -46,11 +47,11 @@ def main():
     # TPU binding: Pallas kernel (grid = the pipelined loop, scratch = the
     # register window), validated against the oracle
     inputs = stencil1d.make_inputs(n=64)
-    fn = lower_to_pallas(module, entry)
+    fn = lower_to_pallas(module, entry, interpret=True)
     out = fn(inputs[0])["Bw"]
     want = stencil1d.oracle(inputs[0])
     np.testing.assert_array_equal(np.asarray(out, np.int64), want)
-    print("TPU binding:   pallas_call(grid=(62,), scratch=VMEM(2)) matches oracle")
+    print("TPU binding:   pallas_call(grid=(62,), scratch=SMEM(2)) matches oracle")
 
     # now break the schedule the way a retiming would (paper Fig. 2) and
     # watch the verifier refuse it statically

@@ -58,9 +58,11 @@ def main():
         first = next(l for l in text.splitlines() if l and not l.startswith(("//", "--")))
         print(f"== {backend}: {len(text.splitlines())} lines | {first[:60]}")
 
-    # -- 4. same IR -> Pallas TPU kernel (interpret mode on CPU) ------------
+    # -- 4. same IR -> Pallas TPU kernel ------------------------------------
+    # interpreted, so this walkthrough runs on any host; without
+    # interpret=True the kernel is compiled for the TPU (chip_smoke.py)
     inputs = array_add.make_inputs(n=64)
-    fn = lower_to_pallas(module, entry)
+    fn = lower_to_pallas(module, entry, interpret=True)
     out = fn(inputs[0], inputs[1])["C"]
     want = array_add.oracle(inputs[0], inputs[1])
     np.testing.assert_array_equal(np.asarray(out, np.int64), want)

@@ -9,6 +9,6 @@ from .resources import (ResourceReport, estimate_resources,  # noqa: F401
                         report_design, report_module, sharing_summary)
 from .lint import (DIALECT_LINTERS, lint_backend, lint_circt,  # noqa: F401
                    lint_systemverilog, lint_verilog, lint_vhdl)
-from .sim import (HAVE_JAX, DiffReport, RTLSimError, RTLSimulator,  # noqa: F401
+from .sim import (DiffReport, RTLSimError, RTLSimulator,  # noqa: F401
                   SimResult, probe_cycles, run_differential, simulator_for,
                   stack_stimulus, verify_rtl_passes)

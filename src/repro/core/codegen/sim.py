@@ -12,11 +12,13 @@ pure array-program step function and runs whole stimulus *batches* through it:
   * ``ShiftReg``/``RegAssign``/``Memory``/``LoopController`` state is
     threaded through the step function with nonblocking (read-old,
     write-new) semantics;
-  * on the JAX backend the single-lane step is ``jax.vmap``-ed over the
-    stimulus batch axis and ``jax.lax.scan``-ed over cycles under
-    ``jax.experimental.enable_x64`` (the global x64 flag is never touched);
-    the NumPy fallback runs the same tape batch-first with a Python cycle
-    loop — still vectorized over stimulus.
+  * on the JAX backend (the default) the single-lane step is
+    ``jax.vmap``-ed over the stimulus batch axis and ``jax.lax.scan``-ed
+    over cycles under a scoped ``jax.enable_x64`` (the global x64 flag is
+    never touched), and runs on JAX's default device; the NumPy backend
+    runs the same tape batch-first on the host with a Python cycle loop —
+    still vectorized over stimulus — and serves as the host-side
+    cross-check in ``verify_rtl_passes``.
 
 Semantics follow the event-driven oracle (``lower.to_sim``): values are bit
 patterns masked to their net width, ``Signed`` sign-extends, division is
@@ -37,6 +39,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from .. import ir
@@ -47,18 +51,6 @@ from .rtl import (REG, WIRE, Binop, CombAssign, Const, Expr, Instance,
                   LoopController, MemRead, Memory, MemWrite, Mux, Net,
                   PortConflictAssert, Ref, Repeat, RegAssign, RTLDesign,
                   RTLModule, ShiftReg, Signed, Unop)
-
-try:  # pragma: no cover - absence exercised via the numpy backend
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-
-    HAVE_JAX = True
-except ImportError:  # pragma: no cover
-    jax = None
-    jnp = None
-    enable_x64 = None
-    HAVE_JAX = False
 
 I64 = np.int64
 
@@ -388,7 +380,11 @@ class SimResult:
     pulse fired; ``arrays[i]`` is the final content of memref argument ``i``
     in its original tensor shape; ``conflicts`` counts §4.5 port-conflict
     cycles per lane; ``trace[p]`` is the per-cycle (T, B) pattern of output
-    port ``p`` when tracing was requested."""
+    port ``p`` when tracing was requested.  ``platform`` names where the
+    run executed (the JAX platform of its arrays, ``"host"`` for the NumPy
+    backend); ``compile_s`` is the trace + XLA compile time this run paid
+    (0.0 when the executable was already compiled) and ``run_s`` the
+    simulation time alone, taken after ``block_until_ready``."""
 
     backend: str
     cycles: int
@@ -399,6 +395,9 @@ class SimResult:
     conflicts: np.ndarray
     conflict_buses: list[str]
     trace: Optional[dict[str, np.ndarray]] = None
+    platform: str = "host"
+    compile_s: float = 0.0
+    run_s: float = 0.0
 
 
 class RTLSimulator:
@@ -406,11 +405,11 @@ class RTLSimulator:
 
     ``design`` is the (possibly hierarchical) RTL design; ``func`` the
     originating ``hir.func`` (argument/result types and memory layout).
-    ``backend`` is ``"jax"``, ``"numpy"`` or ``"auto"`` (jax when present).
+    ``backend`` is ``"jax"`` or ``"numpy"``, the host-side twin.
     """
 
     def __init__(self, design: RTLDesign, func: FuncOp,
-                 entry: Optional[str] = None, backend: str = "auto"):
+                 entry: Optional[str] = None, backend: str = "jax"):
         entry = entry or design.entry
         assert entry is not None, "entry module required"
         self.entry = entry
@@ -418,18 +417,12 @@ class RTLSimulator:
         flat = design.flatten(entry)
         self.binds, self._ext_traced = close_module(flat, func)
         self.flat = flat
-        self.backend = self._resolve_backend(backend)
-        self._jitted: Optional[Callable] = None
+        if backend not in ("jax", "numpy"):
+            raise RTLSimError(f"unknown simulator backend {backend!r}")
+        self.backend = backend
+        # compiled scan executables keyed by (trace, batch, cycles)
+        self._compiled: dict[tuple, tuple[Any, list[str]]] = {}
         self._build()
-
-    @staticmethod
-    def _resolve_backend(backend: str) -> str:
-        if backend == "auto":
-            return "jax" if HAVE_JAX else "numpy"
-        if backend == "jax" and not HAVE_JAX:
-            raise RTLSimError("jax backend requested but jax is unavailable")
-        assert backend in ("jax", "numpy"), backend
-        return backend
 
     # -- compilation ---------------------------------------------------------
     def _build(self) -> None:
@@ -789,30 +782,51 @@ class RTLSimulator:
         xs = np.zeros(cycles, dtype=I64)
         xs[0] = 1
         if self.backend == "jax":
-            final, ys = self._run_jax(state, xs, trace)
+            final, ys, timing = self._run_jax(state, xs, B, trace)
         else:
-            final, ys = self._run_numpy(state, xs, B, trace)
-        return self._collect(final, ys, B, cycles, check_conflicts, trace)
+            final, ys, timing = self._run_numpy(state, xs, B, trace)
+        res = self._collect(final, ys, B, cycles, check_conflicts, trace)
+        res.platform, res.compile_s, res.run_s = timing
+        return res
 
-    def _run_jax(self, state, xs, trace: bool):
-        key = ("trace" if trace else "plain")
-        with enable_x64():
-            if self._jitted is None or self._jitted[0] != key:
-                step, names = self._make_step(_JaxOps(), trace)
-                vstep = jax.vmap(step, in_axes=(0, None))
+    def scan_program(self, trace: bool = False):
+        """The whole batched run as one traceable function
+        ``(state, t_start_per_cycle) -> (final_state, per_cycle_outputs)``
+        (a ``vmap`` over lanes inside a ``lax.scan`` over cycles), and the
+        port names of its per-cycle outputs.  Trace it under
+        ``jax.enable_x64(True)``: state is int64."""
+        step, names = self._make_step(_JaxOps(), trace)
+        vstep = jax.vmap(step, in_axes=(0, None))
 
-                def scanner(s0, xs):
-                    return jax.lax.scan(vstep, s0, xs)
+        def scanner(state, xs):
+            return jax.lax.scan(vstep, state, xs)
 
-                self._jitted = (key, jax.jit(scanner), names)
-            _, fn, names = self._jitted
-            s0 = {k: jnp.asarray(v) for k, v in state.items()}
-            final, ys = fn(s0, jnp.asarray(xs))
+        return scanner, names
+
+    def _run_jax(self, state, xs, B: int, trace: bool):
+        key = (trace, B, len(xs))
+        with jax.enable_x64(True):
+            s0 = jax.device_put(state)
+            xs_d = jax.device_put(xs)
+            compile_s = 0.0
+            if key not in self._compiled:
+                t0 = time.perf_counter()
+                scanner, names = self.scan_program(trace)
+                exe = jax.jit(scanner).lower(s0, xs_d).compile()
+                self._compiled[key] = (exe, names)
+                compile_s = time.perf_counter() - t0
+            exe, names = self._compiled[key]
+            jax.block_until_ready((s0, xs_d))
+            t0 = time.perf_counter()
+            final, ys = jax.block_until_ready(exe(s0, xs_d))
+            run_s = time.perf_counter() - t0
+            platform = xs_d.devices().pop().platform
             final = {k: np.asarray(v) for k, v in final.items()}
             ys = {n: np.asarray(y) for n, y in zip(names, ys)}
-        return final, ys
+        return final, ys, (platform, compile_s, run_s)
 
     def _run_numpy(self, state, xs, B: int, trace: bool):
+        t0 = time.perf_counter()
         step, names = self._make_step(_NumpyOps(B), trace)
         recs: list[tuple] = []
         for t in range(len(xs)):
@@ -821,7 +835,7 @@ class RTLSimulator:
         ys = {n: np.stack([np.broadcast_to(np.asarray(r[i], dtype=I64), (B,))
                            for r in recs])
               for i, n in enumerate(names)}
-        return state, ys
+        return state, ys, ("host", 0.0, time.perf_counter() - t0)
 
     def _collect(self, final, ys, B, cycles, check_conflicts, trace):
         rts = self.func.attrs.get("result_types", [])
@@ -882,7 +896,7 @@ def design_of(mods: dict[str, Any], entry: str) -> RTLDesign:
 
 
 def simulator_for(module: Module, entry: str, *, hierarchy: str = "inline",
-                  backend: str = "auto", rtl_spec: Optional[str] = "default",
+                  backend: str = "jax", rtl_spec: Optional[str] = "default",
                   ) -> tuple[RTLSimulator, Module]:
     """Clone ``module``, run the codegen pipeline and build a simulator.
 
@@ -938,32 +952,26 @@ def fold_in_stimulus(widths: Sequence[int], n_lanes: int,
     masked to the input's bit width.  Unlike sequential generators, fold_in
     streams are stable under lane/input reordering — adding a lane never
     perturbs the values the existing lanes see, so seed-pinned differential
-    suites stay reproducible as they grow.  Falls back to equivalent-shape
-    ``numpy.random.SeedSequence`` spawn streams when jax is absent (values
-    differ across the two generators; each is deterministic per seed)."""
-    masks = [(1 << min(int(w), 63)) - 1 for w in widths]
+    suites stay reproducible as they grow.  Each input's lanes are drawn
+    in one jitted ``vmap`` over the lane index (one device round trip per
+    input)."""
+    key = jax.random.key(seed)
+    lanes = jnp.arange(n_lanes, dtype=jnp.uint32)
     out: list[np.ndarray] = []
-    if HAVE_JAX:
-        key = jax.random.key(seed) if hasattr(jax.random, "key") \
-            else jax.random.PRNGKey(seed)
-        for i, mask in enumerate(masks):
-            ki = jax.random.fold_in(key, i)
-            lanes = []
-            for lane in range(n_lanes):
-                kl = jax.random.fold_in(ki, lane)
-                hi, lo = (int(b) for b in
-                          jax.random.bits(kl, (2,), dtype=jnp.uint32))
-                lanes.append(((hi << 32) | lo) & mask)
-            out.append(np.asarray(lanes, dtype=I64))
-        return out
-    for i, mask in enumerate(masks):
-        lanes = []
-        for lane in range(n_lanes):
-            rng = np.random.default_rng(
-                np.random.SeedSequence(entropy=seed, spawn_key=(i, lane)))
-            lanes.append(int(rng.integers(0, 1 << 63, dtype=np.int64)) & mask)
-        out.append(np.asarray(lanes, dtype=I64))
+    for i, w in enumerate(widths):
+        words = np.asarray(_lane_words(jax.random.fold_in(key, i), lanes))
+        hi = words[:, 0].astype(np.uint64)
+        lo = words[:, 1].astype(np.uint64)
+        mask = np.uint64((1 << min(int(w), 63)) - 1)
+        out.append((((hi << np.uint64(32)) | lo) & mask).astype(I64))
     return out
+
+
+@jax.jit
+def _lane_words(key, lanes):
+    """Two uint32 words per lane from the stream ``fold_in(key, lane)``."""
+    return jax.vmap(lambda lane: jax.random.bits(
+        jax.random.fold_in(key, lane), (2,), dtype=jnp.uint32))(lanes)
 
 
 # ---------------------------------------------------------------------------
@@ -973,6 +981,10 @@ def fold_in_stimulus(widths: Sequence[int], n_lanes: int,
 
 @dataclass
 class DiffReport:
+    """Outcome of ``run_differential``.  ``platform``, ``compile_s`` and
+    ``run_s`` describe the batched simulator run over all ``n_vectors``
+    lanes (see ``SimResult``)."""
+
     kernel: str
     hierarchy: str
     backend: str
@@ -983,6 +995,9 @@ class DiffReport:
     oracle_ok: Optional[bool]
     passes_ok: Optional[dict[str, bool]]
     mismatches: list[str] = field(default_factory=list)
+    platform: str = "host"
+    compile_s: float = 0.0
+    run_s: float = 0.0
 
     @property
     def ok(self) -> bool:
@@ -1006,7 +1021,7 @@ def _result_args(sim: RTLSimulator, res: SimResult, lane: int,
 def run_differential(module: Module, entry: str,
                      args_batch: Sequence[np.ndarray], *,
                      kernel: str = "", hierarchy: str = "inline",
-                     backend: str = "auto", event_lanes: int = 2,
+                     backend: str = "jax", event_lanes: int = 2,
                      oracle: Optional[Callable] = None,
                      oracle_nargs: int = 0, result_arg: int = -1,
                      check_passes: bool = True,
@@ -1086,7 +1101,8 @@ def run_differential(module: Module, entry: str,
         mismatches.extend(pmism)
 
     return DiffReport(kernel or entry, hierarchy, sim.backend, B, cycles,
-                      len(lanes), event_ok, oracle_ok, passes_ok, mismatches)
+                      len(lanes), event_ok, oracle_ok, passes_ok, mismatches,
+                      res.platform, res.compile_s, res.run_s)
 
 
 def verify_rtl_passes(prepared: Module, entry: str,
@@ -1140,7 +1156,7 @@ def verify_rtl_passes(prepared: Module, entry: str,
 
 
 __all__ = [
-    "HAVE_JAX", "RTLSimError", "RTLSimulator", "SimResult", "DiffReport",
+    "RTLSimError", "RTLSimulator", "SimResult", "DiffReport",
     "close_module", "design_of", "simulator_for", "probe_cycles",
     "stack_stimulus", "run_differential", "verify_rtl_passes",
 ]

@@ -34,7 +34,9 @@ from __future__ import annotations
 from contextlib import contextmanager
 from typing import Any, Callable, Optional, Sequence
 
+import jax
 import numpy as np
+from jax.extend import core as jax_core
 
 from .. import ir
 from ..builder import Builder
@@ -147,7 +149,7 @@ _REDUCE_INIT = {"reduce_sum": 0, "reduce_max": -(1 << 30),
 
 SUPPORTED_PRIMITIVES = tuple(sorted(
     set(_ELEMENTWISE) | set(_IDENTITY) | set(_REDUCE_INIT)
-    | {"broadcast_in_dim", "cumsum", "dot_general", "pjit"}))
+    | {"broadcast_in_dim", "cumsum", "dot_general", "jit"}))
 
 
 def _check_int(aval, what: str) -> None:
@@ -238,8 +240,6 @@ class _Tracer:
             "as a traced input instead")
 
     def val(self, env: dict, atom):
-        from jax import core as jax_core
-
         if isinstance(atom, jax_core.Literal):
             return self.lift_const(atom.val)
         return env[atom]
@@ -251,7 +251,7 @@ class _Tracer:
 
     def eval_eqn(self, eqn, env: dict) -> None:
         p = eqn.primitive.name
-        if p == "pjit" or p == "closed_call":
+        if p == "jit" or p == "closed_call":
             inner = eqn.params["jaxpr"]
             sub = {v: self.val(env, a)
                    for v, a in zip(inner.jaxpr.invars, eqn.invars)}
@@ -420,8 +420,6 @@ class _Tracer:
 
 def _walk_jaxprs(jaxpr):
     """Yield jaxpr and every sub-jaxpr reachable through eqn params."""
-    from jax import core as jax_core
-
     yield jaxpr
     for eqn in jaxpr.eqns:
         for v in eqn.params.values():
@@ -451,8 +449,6 @@ def trace(fn: Callable, in_shapes: Sequence[Sequence[int]], *,
     ``schedule=False`` returns the *unscheduled* (erased) design for callers
     that schedule themselves; ``cache`` forwards to the process-wide
     ``ScheduleCache`` keyed by structural fingerprint."""
-    import jax
-
     from ..hls import erase_schedule, hls_schedule
 
     examples = [np.zeros(tuple(s) or (), np.int32) for s in in_shapes]

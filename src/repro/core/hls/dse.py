@@ -864,9 +864,8 @@ def oracle_expected(module: Module, entry: Optional[str],
     ``result_arg``, memoized two ways: the lowered oracle callable is cached
     by structural fingerprint (no re-trace for the same source module) and
     the output array by (fingerprint, input digest) (no re-execution for the
-    same stimulus).  Uses the jax lowering (``lower.to_jax``) when jax is
-    importable, the event-driven interpreter otherwise; both caches respect
-    ``REPRO_HLS_CACHE=0``."""
+    same stimulus).  Uses the jax lowering (``lower.to_jax``); both caches
+    respect ``REPRO_HLS_CACHE=0``."""
     from .scheduler import _cache_enabled
 
     entry = _entry_name(module, entry)
@@ -902,34 +901,20 @@ def oracle_expected(module: Module, entry: Optional[str],
 
 
 def _make_oracle_fn(module: Module, entry: str, rname: str):
-    """Build the oracle callable on a private clone: jax lowering when
-    available, event-driven fallback otherwise.  The returned closure takes
-    the raw input list and returns the ``rname`` result array."""
-    try:
-        from ..lower.to_jax import lower_to_jax
+    """Build the jax-lowered oracle callable on a private clone.  The
+    returned closure takes the raw input list and returns the ``rname``
+    result array."""
+    from ..lower.to_jax import lower_to_jax
 
-        jfn = lower_to_jax(module.clone(), entry)
+    jfn = lower_to_jax(module.clone(), entry)
 
-        def run_jax(inputs):
-            outs = jfn(*[np.array(a, copy=True)
-                         if isinstance(a, np.ndarray) else a
-                         for a in inputs])
-            return np.asarray(outs[rname])
+    def run_jax(inputs):
+        outs = jfn(*[np.array(a, copy=True)
+                     if isinstance(a, np.ndarray) else a
+                     for a in inputs])
+        return np.asarray(outs[rname])
 
-        return run_jax
-    except ImportError:
-        src = module.clone()
-
-        def run_event(inputs):
-            from ..lower import simulate
-
-            args = [np.array(a, copy=True)
-                    if isinstance(a, np.ndarray) else a for a in inputs]
-            simulate(src, entry, args)
-            names = [a.name for a in src.get(entry).args]
-            return np.array(args[names.index(rname)], copy=True)
-
-        return run_event
+    return run_jax
 
 
 # ---------------------------------------------------------------------------
@@ -941,7 +926,7 @@ def sim_verify_front(module: Module, result: DSEResult,
                      entry: Optional[str] = None,
                      args_batch: Optional[Sequence[np.ndarray]] = None, *,
                      pipeline_spec: Optional[str] = None,
-                     backend: str = "auto", margin: int = 16) -> int:
+                     backend: str = "jax", margin: int = 16) -> int:
     """Run every Pareto-front candidate through the vectorized cycle-accurate
     RTL simulator (``core.codegen.sim``) over a whole stimulus batch and
     check each lane's result array against the memoized oracle of the

@@ -1,24 +1,28 @@
-"""HIR -> Pallas TPU lowering (the hardware-adaptation component, DESIGN §3).
+"""HIR -> Pallas TPU lowering (the hardware-adaptation component).
 
 The paper's three design components map onto a ``pl.pallas_call`` as:
 
-  algorithm  -> the kernel body: HIR ops interpreted into jnp ops on Refs;
+  algorithm  -> the kernel body: HIR ops interpreted into scalar jnp ops on
+                Refs;
   schedule   -> the *main* pipelined loop becomes the (sequential) Pallas
-                grid — HIR's II=1 pipelining is the implicitly double-
-                buffered grid; cross-iteration state (HIR register windows /
-                accumulators) becomes VMEM scratch persisting across grid
+                grid; cross-iteration state (HIR register windows /
+                accumulators) becomes scratch persisting across grid
                 steps; prologue/epilogue phases run under
                 ``pl.when(first/last step)``;
-  binding    -> memref arguments become VMEM-blocked inputs/outputs
-                (BlockSpec = whole array for these register-scale kernels);
-                ``hir.alloc`` buffers become VMEM scratch.
+  binding    -> memref arguments become whole-array inputs/outputs and
+                ``hir.alloc`` buffers become scratch, all in SMEM: the body
+                reads and writes one element at a time, and the TPU stores
+                scalars only to SMEM.  A v5e has 1 MiB of SMEM: compiling
+                for one, array_add with 3 x 64 Ki int32 elements was
+                accepted and 3 x 128 Ki refused, so bound designs must keep
+                their memrefs and scratch under that.
 
 Supported subset (covers the paper's benchmark gallery except GEMM): a
 function whose top level is a sequence of phases — constant-bound loops and
 straight-line memory ops — with one *main* ``for`` loop (the largest trip
 count).  The GEMM systolic array is intentionally NOT emulated PE-by-PE: on
 TPU the MXU *is* the systolic array, and its binding is the hand-scheduled
-``repro.kernels.matmul`` (see DESIGN.md §3 "systolic GEMM").
+``repro.kernels.matmul``.
 
 ``hir.delay`` lowers to identity: the *functional* semantics of a verified
 schedule-correct design do not depend on the delays (that is the point of
@@ -121,17 +125,19 @@ class _KernelInterp:
 
 
 def lower_to_pallas(module: Module, func_name: str, *,
-                    interpret: bool = True,
+                    interpret: bool = False,
                     pipeline: Optional[str] = None,
                     allow_downcast: bool = False) -> Callable:
     """Lower ``@func_name`` to a callable mapping input arrays (one per
     read-port memref arg) to a dict of output arrays (write-port args).
 
     ``pipeline`` optionally names a ``PassManager`` spec run on ``module``
-    (in place) before lowering, mirroring ``lower_to_jax``.
+    (in place) before lowering, mirroring ``lower_to_jax``.  ``interpret``
+    runs the kernel body in Pallas's interpreter (the CPU path of the
+    tests); by default the kernel is compiled for the TPU.
 
     Dtype policy (see ``lower.common.pallas_dtype``): ``f64`` memrefs raise
-    ``TypeError`` unless ``allow_downcast=True`` (TPU VMEM compute is f32);
+    ``TypeError`` unless ``allow_downcast=True`` (TPU compute is f32);
     ``f16`` maps to TPU-native ``bfloat16`` with a ``PrecisionWarning``."""
     if pipeline:
         from ..passmgr import PassManager
@@ -185,13 +191,10 @@ def lower_to_pallas(module: Module, func_name: str, *,
     out_shapes = [jax.ShapeDtypeStruct(a.type.shape,
                                        _dtype(a.type.elem, allow_downcast))
                   for a in out_args]
-    scratch = [pltpu.VMEM(al.attrs["base"].shape,
+    scratch = [pltpu.SMEM(al.attrs["base"].shape,
                           _dtype(al.attrs["base"].elem, allow_downcast))
                for al in allocs]
-
-    def _full_spec(shape):
-        rank = len(shape)
-        return pl.BlockSpec(shape, lambda i, r=rank: (0,) * r)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     def fn(*arrays):
         assert len(arrays) == len(in_args), (len(arrays), len(in_args))
@@ -200,8 +203,8 @@ def lower_to_pallas(module: Module, func_name: str, *,
         outs = pl.pallas_call(
             kernel,
             grid=(trip,),
-            in_specs=[_full_spec(a.type.shape) for a in in_args],
-            out_specs=[_full_spec(a.type.shape) for a in out_args],
+            in_specs=[smem] * len(in_args),
+            out_specs=[smem] * len(out_args),
             out_shape=out_shapes,
             scratch_shapes=scratch,
             interpret=interpret,

@@ -5,7 +5,13 @@ DSE evaluation, per-module backend emission) funnels through
 :func:`pool_map`: the worker function must be a top-level callable (the pool
 pickles it by reference) and the payloads must be picklable — in practice,
 printed IR text plus plain config objects, never live RTL trees (whose
-interned expression keys are process-local, see PR 5).
+interned expression keys are process-local).
+
+Workers are started with the ``spawn`` method and pin themselves to JAX's
+CPU platform before running anything: a pool opened by a process that
+already holds the TPU neither forks that process's device client nor opens
+the chip a second time.  The workers only schedule, print text and run the
+event-driven simulator; the device work stays in the parent.
 
 When no pool can be created — sandboxes without ``/dev/shm`` semaphores, a
 missing ``multiprocessing`` start method, restricted CI runners — the mapper
@@ -14,6 +20,8 @@ runs its serial path, which by contract produces the identical result."""
 
 from __future__ import annotations
 
+import multiprocessing
+import os
 import pickle
 import warnings
 from concurrent.futures import ProcessPoolExecutor
@@ -29,6 +37,15 @@ POOL_FALLBACK_ERRORS = (OSError, RuntimeError, BrokenProcessPool,
                         pickle.PicklingError)
 
 
+def _pin_worker_to_cpu() -> None:
+    """Pool initializer: keep this worker, and anything it starts, off the
+    accelerator."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
+
+
 def pool_map(fn: Callable, payloads: Sequence, max_workers: int, *,
              label: str = "work") -> Optional[list]:
     """Map ``fn`` over ``payloads`` on a ``ProcessPoolExecutor``.
@@ -41,7 +58,10 @@ def pool_map(fn: Callable, payloads: Sequence, max_workers: int, *,
     if max_workers <= 1 or len(payloads) <= 1:
         return None
     try:
-        with ProcessPoolExecutor(max_workers=max_workers) as ex:
+        with ProcessPoolExecutor(
+                max_workers=max_workers,
+                mp_context=multiprocessing.get_context("spawn"),
+                initializer=_pin_worker_to_cpu) as ex:
             return list(ex.map(fn, payloads))
     except POOL_FALLBACK_ERRORS as e:
         warnings.warn(

@@ -9,30 +9,21 @@ import; smoke tests and benchmarks see the real single device.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # AxisType landed in jax 0.5; older versions default every axis to Auto
-    from jax.sharding import AxisType
-
-    def _axis_kw(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
-
-    def _axis_kw(n: int) -> dict:
-        return {}
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kw(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh(shape, axes) -> Mesh:
     """Arbitrary mesh (tests, small runs)."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_axis_kw(len(shape)))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def host_device_mesh() -> Mesh:

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import AttentionCfg, ModelCfg
-from ..parallel.api import shard, shard_map_compat
+from ..parallel.api import shard
 from .common import _named_scope, apply_rope, ninit, softcap as _softcap
 
 NEG_INF = -1e30
@@ -268,7 +268,7 @@ def _sharded_flash_decode(q, k, v, idx, cfg: ModelCfg, mesh):
         o = (accs * corr[..., None]).sum(0) / den[..., None]
         return o.reshape(Bq, 1, a.n_heads, Dh)
 
-    fm = shard_map_compat(
+    fm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(None, "model"), P(None, "model"), P()),
         out_specs=P(), axis_names={"model"}, check_vma=False)

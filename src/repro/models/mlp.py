@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelCfg, MoECfg
-from ..parallel.api import shard, shard_map_compat
+from ..parallel.api import shard
 from .common import act_fn, ninit
 
 
@@ -180,7 +180,7 @@ def moe_forward_ep(p, x, cfg: ModelCfg, mesh, dp_axes):
             aux = jax.lax.pmean(aux, a)
         return y, aux
 
-    fm = shard_map_compat(
+    fm = jax.shard_map(
         body, mesh=mesh,
         in_specs=(p_specs, P(dp_axes)),
         out_specs=(P(dp_axes), P()),

@@ -77,21 +77,3 @@ def shard(x, *logical: Optional[str]):
 
 def named_sharding(mesh: Mesh, logical: Sequence[Optional[str]], rules: ShardingRules) -> NamedSharding:
     return NamedSharding(mesh, rules.resolve(logical))
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, axis_names=None,
-                     check_vma: bool = False):
-    """Version-compatible ``jax.shard_map``: newer jax exposes it top-level
-    with ``axis_names`` (manual axes) and ``check_vma``; older releases only
-    have ``jax.experimental.shard_map.shard_map`` with the complementary
-    ``auto`` set and ``check_rep``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    manual = frozenset(axis_names) if axis_names is not None else frozenset(mesh.axis_names)
-    auto = frozenset(mesh.axis_names) - manual
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, auto=auto)

@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .api import shard_map_compat
-
 
 def quantize_int8(x):
     """Per-tensor symmetric int8 quantization; returns (q, scale)."""
@@ -57,7 +55,7 @@ def pod_grads_compressed(grad_fn, params, batch, mesh):
         metrics = jax.tree.map(lambda m: jax.lax.pmean(m, "pod"), metrics)
         return loss, metrics, grads
 
-    fm = shard_map_compat(
+    fm = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(), P("pod")),

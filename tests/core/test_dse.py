@@ -220,6 +220,43 @@ def test_explore_design_pooled_falls_back_serially(monkeypatch):
     assert [p.as_dict() for p in r1.points] == [p.as_dict() for p in r2.points]
 
 
+#: set by the test below in the parent only; a forked worker would inherit
+#: it, a spawned one imports this module afresh
+_PARENT_ONLY = False
+
+
+def _worker_report(_):
+    import jax
+
+    return _PARENT_ONLY, jax.config.jax_platforms, jax.default_backend()
+
+
+def test_pool_after_parent_jax_call_uses_fresh_cpu_workers(monkeypatch):
+    """A pool opened after the parent touched its device spawns workers (no
+    forked copy of the parent's device client) that run on the CPU, and the
+    DSE sweep on it completes with the serial sweep's result."""
+    import warnings
+
+    import jax.numpy as jnp
+
+    from repro.core import pool
+
+    jnp.ones(4).block_until_ready()
+    monkeypatch.setattr(__import__(__name__), "_PARENT_ONLY", True)
+    m, entry, ins, _exp = _gemm_setup()
+    space = design_space(clock_ns=(10.0, 5.0))
+    with warnings.catch_warnings():
+        warnings.simplefilter("error", RuntimeWarning)  # no serial fallback
+        reports = pool.pool_map(_worker_report, [0, 1], 2)
+        pooled = explore_design(m, space, entry=entry, inputs=ins,
+                                max_workers=2)
+    assert reports == [(False, "cpu", "cpu")] * 2
+    serial = explore_design(m, space, entry=entry, inputs=ins)
+    assert [p.as_dict() for p in pooled.points] == \
+        [p.as_dict() for p in serial.points]
+    assert all(p.verified for p in pooled.points)
+
+
 # ---------------------------------------------------------------------------
 # Adversarial cache-key collisions: modules sharing a function fingerprint
 # but differing in pipeline spec, scheduler options, clock, backend or

@@ -77,7 +77,6 @@ def test_mac_differential(hierarchy):
     assert np.array_equal(np.asarray(res.returns[0]), want)
 
 
-@pytest.mark.skipif(not rsim.HAVE_JAX, reason="jax unavailable")
 def test_numpy_and_jax_backends_agree():
     mod, entry = gemm.build(n=4)
     batch = rsim.stack_stimulus(gemm.make_inputs, 32, base_seed=3, n=4)
@@ -145,6 +144,36 @@ def test_stack_stimulus_shapes_and_determinism():
     assert all(np.array_equal(a, b) for a, b in zip(batch, again))
     # lanes differ (distinct seeds)
     assert not np.array_equal(batch[0][0], batch[0][1])
+
+
+def _fold_in_per_lane(widths, n_lanes, seed):
+    """Reference: one fold_in stream and one host round trip per lane."""
+    import jax
+    import jax.numpy as jnp
+
+    key = jax.random.key(seed)
+    out = []
+    for i, w in enumerate(widths):
+        ki = jax.random.fold_in(key, i)
+        lanes = []
+        for lane in range(n_lanes):
+            hi, lo = (int(b) for b in jax.random.bits(
+                jax.random.fold_in(ki, lane), (2,), dtype=jnp.uint32))
+            lanes.append(((hi << 32) | lo) & ((1 << min(w, 63)) - 1))
+        out.append(np.asarray(lanes, dtype=np.int64))
+    return out
+
+
+@pytest.mark.parametrize("widths", [[1, 8, 16], [32, 63, 64]])
+def test_fold_in_stimulus_matches_per_lane_streams(widths):
+    got = rsim.fold_in_stimulus(widths, 9, seed=1234)
+    want = _fold_in_per_lane(widths, 9, seed=1234)
+    for g, w in zip(got, want):
+        assert g.dtype == np.int64
+        assert np.array_equal(g, w)
+    # adding lanes never perturbs the existing ones
+    more = rsim.fold_in_stimulus(widths, 12, seed=1234)
+    assert all(np.array_equal(m[:9], g) for m, g in zip(more, got))
 
 
 def test_unbatched_run_lifts_to_single_lane():

@@ -176,11 +176,7 @@ def _colliding_build():
     return b.module, "collide"
 
 
-@pytest.mark.parametrize("backend", [
-    "numpy",
-    pytest.param("jax", marks=pytest.mark.skipif(
-        not rsim.HAVE_JAX, reason="jax unavailable")),
-])
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
 def test_colliding_schedule_surfaces_conflict_lanes(backend):
     mod, entry = _colliding_build()
     sim, _prepared = rsim.simulator_for(mod, entry, backend=backend)
@@ -194,11 +190,7 @@ def test_colliding_schedule_surfaces_conflict_lanes(backend):
         sim.run(batch, 6, batched=True)
 
 
-@pytest.mark.parametrize("backend", [
-    "numpy",
-    pytest.param("jax", marks=pytest.mark.skipif(
-        not rsim.HAVE_JAX, reason="jax unavailable")),
-])
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
 def test_clean_schedule_has_no_conflicts(backend):
     gal = GALLERY["gemm_shared"]
     mod, entry = gal.build(4)

@@ -5,7 +5,7 @@ Three properties, all derandomized/seeded for CI reproducibility:
   * random hand-built ``RTLModule``s simulate identically before and after
     every RTL pass in ``RTL_PIPELINE_SPEC`` (per-cycle output-port traces);
   * on the same random modules the numpy and jax backends produce identical
-    traces (skipped when jax is absent);
+    traces;
   * on gallery kernels with hypothesis-drawn seeds, the vectorized
     simulator matches the event-driven ``lower.simulate`` oracle exactly.
 
@@ -49,8 +49,7 @@ def _wrap(m):
 
 
 def _stimulus(ins, sd):
-    # jax-native fold_in streams (per-input, per-lane); numpy SeedSequence
-    # fallback keeps the suite runnable without jax.  Widths are capped at
+    # jax-native fold_in streams (per-input, per-lane).  Widths are capped at
     # 16 bits so multi-op datapaths stay inside the simulators' i64 domain.
     return rsim.fold_in_stimulus([min(p.width, 16) for p in ins], LANES,
                                  seed=sd)
@@ -80,7 +79,6 @@ def test_rtl_passes_preserve_cycle_accuracy(m, sd):
         prev = cur
 
 
-@pytest.mark.skipif(not rsim.HAVE_JAX, reason="jax unavailable")
 @seed(20260808)
 @given(rtl_modules(), st.integers(0, 2**16))
 @settings(max_examples=10, deadline=None)

@@ -1,6 +1,8 @@
 """HIR -> Pallas lowering: every loop-nest gallery kernel must match its
-NumPy oracle (interpret mode), and the functional JAX lowering, proving the
-schedule -> grid / state -> scratch mapping preserves the algorithm."""
+NumPy oracle and the functional JAX lowering, proving the schedule -> grid /
+state -> scratch mapping preserves the algorithm.  These run on the CPU, so
+every kernel is called with ``interpret=True``; ``tests/test_tpu_compile.py``
+compiles the same kernels for the TPU."""
 
 import numpy as np
 import pytest
@@ -12,7 +14,7 @@ from repro.core.lower.to_pallas import lower_to_pallas
 
 def _run(build, make_inputs, oracle_args=None):
     module, name = build()
-    fn = lower_to_pallas(module, name)
+    fn = lower_to_pallas(module, name, interpret=True)
     inputs = make_inputs()
     n_in = sum(1 for a in module.get(name).args
                if a.type.port == "r")
@@ -57,7 +59,7 @@ def test_pallas_agrees_with_functional_lowering(gal):
     inputs = gal.make_inputs()
     n_in = sum(1 for a in module.get(name).args if a.type.port == "r")
     jfn = lower_to_jax(module, name)
-    pfn = lower_to_pallas(module, name)
+    pfn = lower_to_pallas(module, name, interpret=True)
     jout = jfn(*inputs)
     pout = pfn(*inputs[:n_in])
     for k, v in pout.items():
@@ -77,7 +79,7 @@ def test_gemm_binds_to_mxu_kernel():
     a, b, _ = gemm.make_inputs()
     want = gemm.oracle(a, b)
     got = ops.matmul(jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32),
-                     bm=16, bn=16, bk=16)
+                     bm=16, bn=16, bk=16, interpret=True)
     np.testing.assert_array_equal(np.asarray(got, np.int64), want)
 
 
@@ -123,7 +125,8 @@ def test_pallas_f64_downcast_is_explicit_and_warned():
 
     module, name = _build_float_add(ir.FloatType(64))
     with pytest.warns(PrecisionWarning, match="f64 -> f32"):
-        fn = lower_to_pallas(module, name, allow_downcast=True)
+        fn = lower_to_pallas(module, name, allow_downcast=True,
+                             interpret=True)
     a = np.arange(8.0)
     b = 2.0 * np.arange(8.0)
     with warnings.catch_warnings():

@@ -27,7 +27,7 @@ def test_matmul(shape, dtype):
     k1, k2 = jax.random.split(jax.random.key(0))
     x = jax.random.normal(k1, (M, K), jnp.float32).astype(dtype)
     y = jax.random.normal(k2, (K, N), jnp.float32).astype(dtype)
-    out = ops.matmul(x, y, bm=128, bn=128, bk=128)
+    out = ops.matmul(x, y, bm=128, bn=128, bk=128, interpret=True)
     want = ref.matmul_ref(x, y)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
@@ -56,7 +56,8 @@ def test_flash_attention(Sq, Sk, H, KvH, D, window, dtype):
     q = jax.random.normal(ks[0], (B, Sq, H, D), jnp.float32).astype(dtype)
     k = jax.random.normal(ks[1], (B, Sk, KvH, D), jnp.float32).astype(dtype)
     v = jax.random.normal(ks[2], (B, Sk, KvH, D), jnp.float32).astype(dtype)
-    out = ops.mha(q, k, v, causal=True, window=window, bq=64, bk=64)
+    out = ops.mha(q, k, v, causal=True, window=window, bq=64, bk=64,
+                  interpret=True)
     # oracle expects (B,H,S,D) with KV repeated to H
     rep = H // KvH
     kk = jnp.repeat(jnp.swapaxes(k, 1, 2), rep, axis=1)
@@ -76,7 +77,7 @@ def test_flash_vs_model_oracle():
     q = jax.random.normal(ks[0], (B, S, H, D))
     k = jax.random.normal(ks[1], (B, S, H, D))
     v = jax.random.normal(ks[2], (B, S, H, D))
-    out = ops.mha(q, k, v, bq=64, bk=64)
+    out = ops.mha(q, k, v, bq=64, bk=64, interpret=True)
     want = model_flash(q, k, v, causal=True, kv_chunk=64)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -97,7 +98,7 @@ def test_decode_attention(L, H, KvH, D, length, dtype):
     q = jax.random.normal(ks[0], (B, H, D), jnp.float32).astype(dtype)
     k = jax.random.normal(ks[1], (B, L, KvH, D), jnp.float32).astype(dtype)
     v = jax.random.normal(ks[2], (B, L, KvH, D), jnp.float32).astype(dtype)
-    out = ops.decode(q, k, v, length, bk=128)
+    out = ops.decode(q, k, v, length, bk=128, interpret=True)
     rep = H // KvH
     kk = jnp.repeat(k, rep, axis=2)
     vv = jnp.repeat(v, rep, axis=2)
@@ -114,7 +115,7 @@ def test_decode_partial_merge_equals_full():
     q = jax.random.normal(ks[0], (B, H, D))
     k = jax.random.normal(ks[1], (B, L, H, D))
     v = jax.random.normal(ks[2], (B, L, H, D))
-    full = ops.decode(q, k, v, L, bk=128)
+    full = ops.decode(q, k, v, L, bk=128, interpret=True)
     shard = L // S
     outs, ms, ls = [], [], []
     for s in range(S):
@@ -145,7 +146,7 @@ def test_ssd_scan(S, H, P, N, chunk, dtype):
     dA = -jax.random.uniform(ks[1], (B, S, H), dtype, 0.01, 0.5)
     Bc = jax.random.normal(ks[2], (B, S, N), dtype)
     Cc = jax.random.normal(ks[3], (B, S, N), dtype)
-    y = ops.ssd_scan(xdt, dA, Bc, Cc, chunk=chunk)
+    y = ops.ssd_scan(xdt, dA, Bc, Cc, chunk=chunk, interpret=True)
     want, _ = ref.ssd_scan_ref(xdt, dA, Bc, Cc)
     np.testing.assert_allclose(np.asarray(y), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -164,7 +165,8 @@ def test_ssd_kernel_matches_model_core():
     D = jnp.zeros((H,))
     want, _ = ssd_core_chunked(xh, dt, A, Bc, Cc, D, chunk=16)
     # kernel takes dt-weighted inputs and per-step dA
-    y = ops.ssd_scan(xh * dt[..., None], dt * A[None, None, :], Bc, Cc, chunk=16)
+    y = ops.ssd_scan(xh * dt[..., None], dt * A[None, None, :], Bc, Cc,
+                     chunk=16, interpret=True)
     np.testing.assert_allclose(np.asarray(y), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
 
@@ -183,7 +185,7 @@ def test_rglru_scan(S, D, bs, bd, dtype):
     B = 2
     a = jax.random.uniform(ks[0], (B, S, D), dtype, 0.5, 0.99)
     b = jax.random.normal(ks[1], (B, S, D), dtype)
-    h = ops.rglru_scan(a, b, bs=bs, bd=bd)
+    h = ops.rglru_scan(a, b, bs=bs, bd=bd, interpret=True)
     want, _ = ref.rglru_scan_ref(a, b)
     np.testing.assert_allclose(np.asarray(h), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
@@ -202,7 +204,7 @@ def test_rglru_kernel_matches_model_scan():
         return a1 * a2, a2 * b1 + b2
 
     _, want = jax.lax.associative_scan(comb, (a, b), axis=1)
-    h = ops.rglru_scan(a, b, bs=16, bd=16)
+    h = ops.rglru_scan(a, b, bs=16, bd=16, interpret=True)
     np.testing.assert_allclose(np.asarray(h), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 
@@ -225,7 +227,7 @@ def test_mha_padded_kv_is_masked(causal, Sq, Sk):
     q = jax.random.normal(ks[0], (B, Sq, H, D))
     k = jax.random.normal(ks[1], (B, Sk, H, D))
     v = jax.random.normal(ks[2], (B, Sk, H, D))
-    out = ops.mha(q, k, v, causal=causal, bq=32, bk=32)
+    out = ops.mha(q, k, v, causal=causal, bq=32, bk=32, interpret=True)
     want = ref.flash_attention_ref(jnp.swapaxes(q, 1, 2),
                                    jnp.swapaxes(k, 1, 2),
                                    jnp.swapaxes(v, 1, 2), causal=causal)

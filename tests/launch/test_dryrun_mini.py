@@ -36,7 +36,6 @@ from repro.train.steps import make_serve_step, make_train_step
 
 cfg = get_smoke_config({arch!r})
 shape = ShapeCfg("mini", seq_len=16, global_batch=8, kind={kind!r})
-# version-compatible mesh helper (AxisType only exists on jax >= 0.5)
 from repro.launch.mesh import make_mesh
 mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 rules = rules_for(cfg, mesh, {mode!r}, batch=8)

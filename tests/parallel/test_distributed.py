@@ -8,18 +8,7 @@ import subprocess
 import sys
 import textwrap
 
-import jax
-import pytest
-
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-
-# Partial-manual shard_map over a sub-mesh (manual "pod", auto data/model)
-# needs the modern jax.shard_map + XLA: the legacy SPMD partitioner crashes
-# on manual subgroups (IsManualSubgroup check) / lacks PartitionId support.
-requires_partial_manual = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-manual shard_map needs modern jax.shard_map/XLA",
-)
 
 
 def _run(code: str) -> str:
@@ -34,7 +23,7 @@ def _run(code: str) -> str:
 
 MOE_EP_TEMPLATE = """
 import jax, jax.numpy as jnp, numpy as np
-from repro.launch.mesh import make_mesh as _compat_mesh
+from repro.launch.mesh import make_mesh
 from repro.configs.registry import get_smoke_config
 from repro.models import mlp
 from repro.parallel.api import use_rules
@@ -42,7 +31,7 @@ from repro.parallel.rules import rules_for
 
 cfg = get_smoke_config({arch!r})
 {cfg_patch}
-mesh = _compat_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 rules = rules_for(cfg, mesh, "train", batch=8, moe_ep=True)
 p = mlp.init_moe(jax.random.key(0), cfg)
 x = jax.random.normal(jax.random.key(1), (8, 4, cfg.d_model), jnp.float32)
@@ -98,14 +87,13 @@ def test_moe_ep_ff_sharded_matches_local():
     assert "MOE_EP_OK" in out
 
 
-@requires_partial_manual
 def test_compressed_pod_grads_close_to_exact():
     out = _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.launch.mesh import make_mesh as _compat_mesh
+    from repro.launch.mesh import make_mesh
     from repro.parallel.compression import pod_grads_compressed, compressed_psum, quantize_int8
 
-    mesh = _compat_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     w = jax.random.normal(jax.random.key(0), (64, 64)) * 0.1
     x = jax.random.normal(jax.random.key(1), (16, 64))
 
@@ -135,11 +123,11 @@ def test_seq_shard_fallback_rules():
     must shard the sequence instead (and only then)."""
     out = _run("""
     import jax
-    from repro.launch.mesh import make_mesh as _compat_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs.registry import get_config
     from repro.parallel.rules import rules_for
 
-    mesh = _compat_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     # qwen2.5-14b: 40 heads, model=2 divides -> no fallback even if enabled
     r = rules_for(get_config("qwen2.5-14b"), mesh, "prefill",
                   seq_shard_fallback=True)
@@ -153,20 +141,19 @@ def test_seq_shard_fallback_rules():
     assert "RULES_OK" in out
 
 
-@requires_partial_manual
 def test_sharded_flash_decode_matches_reference():
     """The shard_map partial-softmax decode (kv cache sharded over model)
     must equal the single-device decode step exactly."""
     out = _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.launch.mesh import make_mesh as _compat_mesh
+    from repro.launch.mesh import make_mesh
     from repro.configs.registry import get_smoke_config
     from repro.models import attention, transformer
     from repro.parallel.api import use_rules
     from repro.parallel.rules import rules_for
 
     cfg = get_smoke_config("tinyllama-1.1b")
-    mesh = _compat_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     p = attention.init_attn(jax.random.key(0), cfg)
     B, L = 4, 32
     cache = attention.init_attn_cache(B, L, cfg)

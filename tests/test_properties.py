@@ -240,7 +240,7 @@ def test_matmul_kernel_any_shape(m, k, n):
     k1, k2 = jax.random.split(jax.random.key(m * 1000 + k * 100 + n))
     x = jax.random.normal(k1, (m, k), jnp.float32)
     y = jax.random.normal(k2, (k, n), jnp.float32)
-    out = ops.matmul(x, y, bm=16, bn=16, bk=16)
+    out = ops.matmul(x, y, bm=16, bn=16, bk=16, interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref.matmul_ref(x, y)),
                                rtol=3e-5, atol=3e-5)
 
@@ -253,6 +253,6 @@ def test_rglru_kernel_any_shape(S, D):
     k1, k2 = jax.random.split(jax.random.key(S * 100 + D))
     a = jax.random.uniform(k1, (2, S, D), jnp.float32, 0.5, 0.999)
     b = jax.random.normal(k2, (2, S, D), jnp.float32)
-    h = ops.rglru_scan(a, b, bs=16, bd=16)
+    h = ops.rglru_scan(a, b, bs=16, bd=16, interpret=True)
     want, _ = ref.rglru_scan_ref(a, b)
     np.testing.assert_allclose(np.asarray(h), np.asarray(want), rtol=2e-4, atol=2e-4)
