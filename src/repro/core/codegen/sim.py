@@ -35,7 +35,6 @@ memory/return state of each pass's input design against its output design.
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional, Sequence
 
@@ -46,6 +45,7 @@ import numpy as np
 from .. import ir
 from ..ir import FuncOp, IntType, MemrefType, Module
 from ..passmgr import PassManager
+from ..trace import count, span
 from . import rtl
 from .rtl import (REG, WIRE, Binop, CombAssign, Const, Expr, Instance,
                   LoopController, MemRead, Memory, MemWrite, Mux, Net,
@@ -382,9 +382,11 @@ class SimResult:
     cycles per lane; ``trace[p]`` is the per-cycle (T, B) pattern of output
     port ``p`` when tracing was requested.  ``platform`` names where the
     run executed (the JAX platform of its arrays, ``"host"`` for the NumPy
-    backend); ``compile_s`` is the trace + XLA compile time this run paid
-    (0.0 when the executable was already compiled) and ``run_s`` the
-    simulation time alone, taken after ``block_until_ready``."""
+    backend); ``compile_s`` is what this run paid to build the step
+    function, trace and lower the scan and compile it (the ``hir.sim.lower``
+    and ``hir.sim.compile`` spans; 0.0 when the executable was already
+    compiled) and ``run_s`` the simulation time alone, taken after
+    ``block_until_ready`` (the ``hir.sim.scan`` span)."""
 
     backend: str
     cycles: int
@@ -408,6 +410,7 @@ class RTLSimulator:
     ``backend`` is ``"jax"`` or ``"numpy"``, the host-side twin.
     """
 
+    @span("hir.sim.build")
     def __init__(self, design: RTLDesign, func: FuncOp,
                  entry: Optional[str] = None, backend: str = "jax"):
         entry = entry or design.entry
@@ -723,6 +726,7 @@ class RTLSimulator:
         inv = np.argsort(perm)
         return np.ascontiguousarray(np.transpose(r, inv))
 
+    @span("hir.sim.layout")
     def _init_state(self, args: Sequence[Any], B: int) -> dict[str, np.ndarray]:
         state: dict[str, np.ndarray] = {}
         for key, shape in self.state_shape.items():
@@ -748,6 +752,7 @@ class RTLSimulator:
         return state
 
     # -- execution -----------------------------------------------------------
+    @span("hir.sim.run")
     def run(self, args: Sequence[Any], cycles: int, batched: bool = False,
             check_conflicts: bool = True, trace: bool = False) -> SimResult:
         """Simulate ``cycles`` cycles of the design over a stimulus batch.
@@ -756,16 +761,10 @@ class RTLSimulator:
         (B,) arrays) and numpy arrays of the memref shape ((B, *shape) when
         ``batched``).  ``t_start`` pulses at cycle 0.  Unlike the
         event-driven simulator the input arrays are never mutated."""
-        if not batched:
-            lifted = []
-            for b, a in zip(self.binds, list(args)):
-                if b.kind == "scalar":
-                    lifted.append(np.asarray([a], dtype=I64))
-                else:
-                    lifted.append(np.asarray(a, dtype=I64)[None])
-            res = self.run(lifted, cycles, batched=True,
-                           check_conflicts=check_conflicts, trace=trace)
-            return res
+        if not batched:  # one lane
+            args = [np.asarray([a], dtype=I64) if b.kind == "scalar"
+                    else np.asarray(a, dtype=I64)[None]
+                    for b, a in zip(self.binds, list(args))]
         if len(args) != len(self.binds):
             raise RTLSimError(f"expected {len(self.binds)} args")
         B = None
@@ -806,37 +805,49 @@ class RTLSimulator:
     def _run_jax(self, state, xs, B: int, trace: bool):
         key = (trace, B, len(xs))
         with jax.enable_x64(True):
-            s0 = jax.device_put(state)
-            xs_d = jax.device_put(xs)
+            with span("hir.sim.put"):
+                s0 = jax.device_put(state)
+                xs_d = jax.device_put(xs)
+                jax.block_until_ready((s0, xs_d))
+            count("hir.sim.leaves_in", len(state) + 1)
+            count("hir.sim.bytes_in",
+                  sum(a.nbytes for a in state.values()) + xs.nbytes)
             compile_s = 0.0
             if key not in self._compiled:
-                t0 = time.perf_counter()
-                scanner, names = self.scan_program(trace)
-                exe = jax.jit(scanner).lower(s0, xs_d).compile()
-                self._compiled[key] = (exe, names)
-                compile_s = time.perf_counter() - t0
+                count("hir.sim.compiles")
+                with span("hir.sim.lower") as lower:
+                    scanner, names = self.scan_program(trace)
+                    lowered = jax.jit(scanner).lower(s0, xs_d)
+                with span("hir.sim.compile") as comp:
+                    self._compiled[key] = (lowered.compile(), names)
+                compile_s = lower.seconds + comp.seconds
             exe, names = self._compiled[key]
-            jax.block_until_ready((s0, xs_d))
-            t0 = time.perf_counter()
-            final, ys = jax.block_until_ready(exe(s0, xs_d))
-            run_s = time.perf_counter() - t0
+            with span("hir.sim.scan") as scan:
+                final, ys = jax.block_until_ready(exe(s0, xs_d))
             platform = xs_d.devices().pop().platform
-            final = {k: np.asarray(v) for k, v in final.items()}
-            ys = {n: np.asarray(y) for n, y in zip(names, ys)}
-        return final, ys, (platform, compile_s, run_s)
+            with span("hir.sim.fetch"):
+                final = {k: np.asarray(v) for k, v in final.items()}
+                ys = {n: np.asarray(y) for n, y in zip(names, ys)}
+            count("hir.sim.leaves_out", len(final) + len(ys))
+            count("hir.sim.bytes_out",
+                  sum(a.nbytes for a in final.values())
+                  + sum(y.nbytes for y in ys.values()))
+        return final, ys, (platform, compile_s, scan.seconds)
 
     def _run_numpy(self, state, xs, B: int, trace: bool):
-        t0 = time.perf_counter()
-        step, names = self._make_step(_NumpyOps(B), trace)
-        recs: list[tuple] = []
-        for t in range(len(xs)):
-            state, outs = step(state, I64(xs[t]))
-            recs.append(outs)
-        ys = {n: np.stack([np.broadcast_to(np.asarray(r[i], dtype=I64), (B,))
-                           for r in recs])
-              for i, n in enumerate(names)}
-        return state, ys, ("host", 0.0, time.perf_counter() - t0)
+        with span("hir.sim.scan") as scan:
+            step, names = self._make_step(_NumpyOps(B), trace)
+            recs: list[tuple] = []
+            for t in range(len(xs)):
+                state, outs = step(state, I64(xs[t]))
+                recs.append(outs)
+            ys = {n: np.stack([np.broadcast_to(np.asarray(r[i], dtype=I64),
+                                               (B,))
+                               for r in recs])
+                  for i, n in enumerate(names)}
+        return state, ys, ("host", 0.0, scan.seconds)
 
+    @span("hir.sim.collect")
     def _collect(self, final, ys, B, cycles, check_conflicts, trace):
         rts = self.func.attrs.get("result_types", [])
         returns, valids = [], []
@@ -913,6 +924,7 @@ def simulator_for(module: Module, entry: str, *, hierarchy: str = "inline",
     return sim, prepared
 
 
+@span("hir.sim.probe")
 def probe_cycles(prepared: Module, entry: str, args: Sequence[Any],
                  margin: int = 16) -> int:
     """Cycle budget for a batched run: one event-driven simulation on fresh
@@ -983,7 +995,8 @@ def _lane_words(key, lanes):
 class DiffReport:
     """Outcome of ``run_differential``.  ``platform``, ``compile_s`` and
     ``run_s`` describe the batched simulator run over all ``n_vectors``
-    lanes (see ``SimResult``)."""
+    lanes (see ``SimResult``): ``compile_s`` is the step build, trace,
+    lowering and XLA compile of the scan, ``run_s`` the scan alone."""
 
     kernel: str
     hierarchy: str
@@ -1018,6 +1031,7 @@ def _result_args(sim: RTLSimulator, res: SimResult, lane: int,
     return out
 
 
+@span("hir.diff")
 def run_differential(module: Module, entry: str,
                      args_batch: Sequence[np.ndarray], *,
                      kernel: str = "", hierarchy: str = "inline",
@@ -1037,8 +1051,6 @@ def run_differential(module: Module, entry: str,
     pipeline one pass at a time, asserting per-cycle result-port traces and
     final state match between every pass input and output
     (``verify_rtl_passes``)."""
-    from ..lower.to_sim import simulate
-
     mismatches: list[str] = []
     sim, prepared = simulator_for(module, entry, hierarchy=hierarchy,
                                   backend=backend)
@@ -1047,9 +1059,37 @@ def run_differential(module: Module, entry: str,
     cycles = probe_cycles(prepared, entry, single0)
     res = sim.run(args_batch, cycles, batched=True)
 
-    # (a) event-driven oracle on sample lanes
-    event_ok = True
     lanes = list(range(min(event_lanes, B)))
+    event_ok = _check_event_lanes(sim, prepared, entry, args_batch, res,
+                                  lanes, mismatches)
+    oracle_ok: Optional[bool] = None
+    if oracle is not None:
+        ridx = result_arg if result_arg >= 0 else len(args_batch) - 1
+        oracle_ok = _check_oracle(oracle, oracle_nargs, args_batch,
+                                  res.arrays[ridx], mismatches)
+
+    passes_ok = None
+    if check_passes:
+        sub = [np.asarray(a)[:min(pass_lanes, B)] for a in args_batch]
+        with span("hir.diff.passes"):
+            passes_ok, pmism = verify_rtl_passes(
+                prepared, entry, sub, cycles, hierarchy=hierarchy)
+        mismatches.extend(pmism)
+
+    return DiffReport(kernel or entry, hierarchy, sim.backend, B, cycles,
+                      len(lanes), event_ok, oracle_ok, passes_ok, mismatches,
+                      res.platform, res.compile_s, res.run_s)
+
+
+@span("hir.diff.event_lanes")
+def _check_event_lanes(sim: RTLSimulator, prepared: Module, entry: str,
+                       args_batch: Sequence[np.ndarray], res: SimResult,
+                       lanes: list[int], mismatches: list[str]) -> bool:
+    """Re-run ``lanes`` through the event-driven simulator; compare final
+    memory arrays and scalar returns with the batched run."""
+    from ..lower.to_sim import simulate
+
+    event_ok = True
     for k in lanes:
         ev_args: list[Any] = []
         for b, a in zip(sim.binds, args_batch):
@@ -1077,32 +1117,22 @@ def run_differential(module: Module, entry: str,
                 event_ok = False
                 mismatches.append(
                     f"lane {k} result_{j}: {int(res.returns[j][k])} != {rv}")
+    return event_ok
 
-    # (b) jax/numpy functional oracle on every lane
-    oracle_ok: Optional[bool] = None
-    if oracle is not None:
-        oracle_ok = True
-        ridx = result_arg if result_arg >= 0 else len(args_batch) - 1
-        for k in range(B):
-            want = np.asarray(
-                oracle(*[np.asarray(args_batch[i])[k]
-                         for i in range(oracle_nargs)]))
-            got = res.arrays[ridx][k]
-            if not np.array_equal(got.astype(I64), want.astype(I64)):
-                oracle_ok = False
-                mismatches.append(f"lane {k}: vectorized != oracle")
-                break
 
-    passes_ok = None
-    if check_passes:
-        sub = [np.asarray(a)[:min(pass_lanes, B)] for a in args_batch]
-        passes_ok, pmism = verify_rtl_passes(
-            prepared, entry, sub, cycles, hierarchy=hierarchy)
-        mismatches.extend(pmism)
-
-    return DiffReport(kernel or entry, hierarchy, sim.backend, B, cycles,
-                      len(lanes), event_ok, oracle_ok, passes_ok, mismatches,
-                      res.platform, res.compile_s, res.run_s)
+@span("hir.diff.oracle")
+def _check_oracle(oracle: Callable, oracle_nargs: int,
+                  args_batch: Sequence[np.ndarray], got: np.ndarray,
+                  mismatches: list[str]) -> bool:
+    """Every lane of the design's result memref against the functional
+    oracle; stops at the first mismatch."""
+    for k in range(got.shape[0]):
+        want = np.asarray(oracle(*[np.asarray(args_batch[i])[k]
+                                   for i in range(oracle_nargs)]))
+        if not np.array_equal(got[k].astype(I64), want.astype(I64)):
+            mismatches.append(f"lane {k}: vectorized != oracle")
+            return False
+    return True
 
 
 def verify_rtl_passes(prepared: Module, entry: str,

@@ -43,6 +43,7 @@ from typing import Optional
 from .. import ir
 from ..ir import ForOp, FuncOp, MemrefType, Module, Operation, Region, Time, Value
 from ..passmgr import AnalysisManager, PassManager
+from ..trace import span
 from . import rtl
 from .rtl import (Binop, CombAssign, Const, Expr, Instance, LoopController,
                   MemRead, Memory, MemWrite, Mux, PortConflictAssert, Ref,
@@ -1108,6 +1109,7 @@ def lower_to_rtl(module: Module, emit_names: list[str],
     return design
 
 
+@span("hir.codegen")
 def generate_verilog(module: Module, entry: Optional[str] = None, run_unroll: bool = True,
                      pass_manager: Optional[PassManager] = None,
                      am: Optional[AnalysisManager] = None,
@@ -1150,7 +1152,10 @@ def generate_verilog(module: Module, entry: Optional[str] = None, run_unroll: bo
     in the PassManager schema — ``{name: {invocations, rewrites, wall_s}}``
     — covering the pre-codegen HIR passes, the ``lower`` phase, every RTL
     netlist pass and the ``emit:<backend>`` phase, so benchmarks report the
-    whole pipeline through one accounting scheme.
+    whole pipeline through one accounting scheme.  Each ``wall_s`` is the
+    duration of that region's span (``hir.pass.<name>``,
+    ``hir.codegen.lower``, ``hir.codegen.emit``; the whole call is
+    ``hir.codegen``).
 
     ``func_cache`` (a ``core.hls.dse.FuncCodegenCache``) switches codegen to
     *per-function incremental* mode: each emitted module's pipeline run,
@@ -1170,8 +1175,6 @@ def generate_verilog(module: Module, entry: Optional[str] = None, run_unroll: bo
     byte-identical to serial; on pool failure it degrades to the serial path
     with a ``RuntimeWarning``.  Parallel results carry ``rtl=None`` (like
     disk-cache hits): callers needing RTL structure regenerate it."""
-    import time as _time
-
     assert hierarchy in ("inline", "modules"), hierarchy
 
     def _note(name: str, wall: float, rewrites: int = 0) -> None:
@@ -1234,11 +1237,11 @@ def generate_verilog(module: Module, entry: Optional[str] = None, run_unroll: bo
 
         trivial = _trivial_funcs(module) if hierarchy == "modules" else set()
         order = _design_order(module, emit_names, hierarchy, trivial)
-        t0 = _time.perf_counter()
-        rows = emit_design_parallel(module, order, hierarchy, rtl_spec,
-                                    backend, max_workers, entry=entry)
+        with span("hir.codegen.emit") as timed:
+            rows = emit_design_parallel(module, order, hierarchy, rtl_spec,
+                                        backend, max_workers, entry=entry)
         if rows is not None:
-            _note(f"emit:{backend}", _time.perf_counter() - t0)
+            _note(f"emit:{backend}", timed.seconds)
             return {name: VerilogModule(name, text, nl, None, backend=backend)
                     for name, text, nl in rows}
         # no pool (RuntimeWarning already emitted): serial path below
@@ -1246,9 +1249,10 @@ def generate_verilog(module: Module, entry: Optional[str] = None, run_unroll: bo
     # this call keys is interned after the clear, so sharing within the
     # design is unaffected; see rtl.clear_key_intern)
     rtl.clear_key_intern()
-    t0 = _time.perf_counter()
-    design = lower_to_rtl(module, emit_names, hierarchy=hierarchy, entry=entry)
-    _note("lower", _time.perf_counter() - t0)
+    with span("hir.codegen.lower") as timed:
+        design = lower_to_rtl(module, emit_names, hierarchy=hierarchy,
+                              entry=entry)
+    _note("lower", timed.seconds)
 
     if rtl_pass_manager is not None:
         rtl_pass_manager.run(design)
@@ -1264,13 +1268,14 @@ def generate_verilog(module: Module, entry: Optional[str] = None, run_unroll: bo
     from .backends import get_printer
 
     printer = get_printer(backend)
-    t0 = _time.perf_counter()
-    modmap = printer.module_name_map(design.modules)
-    out: dict[str, VerilogModule] = {}
-    for name, m in design.modules.items():
-        text = printer.print_module(m, modmap=modmap, design=design)
-        out[name] = VerilogModule(name, text, netlist_of(m), m, backend=backend)
-    _note(f"emit:{backend}", _time.perf_counter() - t0)
+    with span("hir.codegen.emit") as timed:
+        modmap = printer.module_name_map(design.modules)
+        out: dict[str, VerilogModule] = {}
+        for name, m in design.modules.items():
+            text = printer.print_module(m, modmap=modmap, design=design)
+            out[name] = VerilogModule(name, text, netlist_of(m), m,
+                                      backend=backend)
+    _note(f"emit:{backend}", timed.seconds)
     return out
 
 
@@ -1287,8 +1292,6 @@ def _generate_incremental(module: Module, entry: Optional[str],
     the output.  Byte-identity with the cold path holds because the design's
     full module-name order (which pins the printer's first-come name
     legalization) is part of every cache key."""
-    import time as _time
-
     from ..passmgr import CODEGEN_PIPELINE_SPEC
 
     emit_names = [entry] if entry is not None else [
@@ -1344,16 +1347,16 @@ def _generate_incremental(module: Module, entry: Optional[str],
     _ensure_recursion_headroom()
     design = RTLDesign(entry=entry)
     lowered: dict[str, RTLModule] = {}
-    t0 = _time.perf_counter()
-    for name in order:  # callees precede callers by construction
-        if name in entries:
-            m = entries[name].rtl
-        else:
-            m = FuncLowering(module.funcs[name], module, lowered=lowered,
-                             hierarchy=hierarchy).lower()
-        lowered[name] = m
-        design.add(m)
-    _note("lower", _time.perf_counter() - t0)
+    with span("hir.codegen.lower") as timed:
+        for name in order:  # callees precede callers by construction
+            if name in entries:
+                m = entries[name].rtl
+            else:
+                m = FuncLowering(module.funcs[name], module, lowered=lowered,
+                                 hierarchy=hierarchy).lower()
+            lowered[name] = m
+            design.add(m)
+    _note("lower", timed.seconds)
 
     if rtl_spec and misses:
         # keep the entry annotation when the entry itself is re-optimized:
@@ -1369,19 +1372,19 @@ def _generate_incremental(module: Module, entry: Optional[str],
     from .backends import get_printer
 
     printer = get_printer(backend)
-    t0 = _time.perf_counter()
-    modmap = printer.module_name_map(design.modules)
-    out: dict[str, VerilogModule] = {}
-    for name in order:
-        if name in entries:
-            e = entries[name]
-            out[name] = VerilogModule(name, e.text, e.netlist, e.rtl,
-                                      backend=backend)
-        else:
-            m = design.modules[name]
-            text = printer.print_module(m, modmap=modmap, design=design)
-            nl = netlist_of(m)
-            out[name] = VerilogModule(name, text, nl, m, backend=backend)
-            func_cache.put(keys[name], module.funcs[name], m, text, nl)
-    _note(f"emit:{backend}", _time.perf_counter() - t0)
+    with span("hir.codegen.emit") as timed:
+        modmap = printer.module_name_map(design.modules)
+        out: dict[str, VerilogModule] = {}
+        for name in order:
+            if name in entries:
+                e = entries[name]
+                out[name] = VerilogModule(name, e.text, e.netlist, e.rtl,
+                                          backend=backend)
+            else:
+                m = design.modules[name]
+                text = printer.print_module(m, modmap=modmap, design=design)
+                nl = netlist_of(m)
+                out[name] = VerilogModule(name, text, nl, m, backend=backend)
+                func_cache.put(keys[name], module.funcs[name], m, text, nl)
+    _note(f"emit:{backend}", timed.seconds)
     return out

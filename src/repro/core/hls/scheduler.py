@@ -57,6 +57,7 @@ from ..analysis import (MemTouches, analyze_loops, build_dependence_edges,
 from ..ir import ForOp, FuncOp, Module, Operation, Region, Time, Value
 from ..schedule import (CLOCK_NS, MAX_II, SearchState, balance_delays,
                         recurrence_mii, try_modulo_schedule)
+from ..trace import span
 
 
 @dataclass(frozen=True)
@@ -432,6 +433,7 @@ def _cache_enabled() -> bool:
     return os.environ.get("REPRO_HLS_CACHE", "1") != "0"
 
 
+@span("hir.hls.schedule")
 def hls_schedule(module: Module, pipeline_loops: bool = True,
                  options: Optional[SchedulerOptions] = None,
                  cache=None, max_workers: int = 1) -> HLSResult:

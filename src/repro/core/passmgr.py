@@ -39,12 +39,12 @@ The module also hosts the analysis layer (MLIR's AnalysisManager):
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Optional, Sequence, Type, Union
 
 from .ir import FuncOp, Module
 from .rewrite import RewritePatternSet, apply_patterns_greedily
+from .trace import span
 
 # ---------------------------------------------------------------------------
 # Analyses: registry + AnalysisManager
@@ -409,9 +409,9 @@ class PassManager:
                 if seen_at.get(i) == total and last_n.get(i) == 0:
                     continue  # clean and module untouched since: skip
                 p.am = self.analysis_manager
-                t0 = time.perf_counter()
-                n = p.run(module)
-                st.wall_s += time.perf_counter() - t0
+                with span(f"hir.pass.{p.name}") as timed:
+                    n = p.run(module)
+                st.wall_s += timed.seconds
                 st.invocations += 1
                 st.rewrites += n
                 total += n
