@@ -12,13 +12,21 @@ pure array-program step function and runs whole stimulus *batches* through it:
   * ``ShiftReg``/``RegAssign``/``Memory``/``LoopController`` state is
     threaded through the step function with nonblocking (read-old,
     write-new) semantics;
-  * on the JAX backend (the default) the single-lane step is
-    ``jax.vmap``-ed over the stimulus batch axis and ``jax.lax.scan``-ed
-    over cycles under a scoped ``jax.enable_x64`` (the global x64 flag is
-    never touched), and runs on JAX's default device; the NumPy backend
-    runs the same tape batch-first on the host with a Python cycle loop —
-    still vectorized over stimulus — and serves as the host-side
-    cross-check in ``verify_rtl_passes``.
+  * on the JAX backend (the default) the step is ``jax.lax.scan``-ed over
+    cycles under a scoped ``jax.enable_x64`` (the global x64 flag is never
+    touched) and runs on JAX's default device.  A static analysis splits
+    the nets into lane-uniform and lane-varying (``RTLSimulator.varying``:
+    whatever reads a scalar input, a register-bank cell or memory read
+    data, transitively).  Each cycle the uniform part — ``t_start``, the
+    controllers of fixed loops, counters, delayed control — is evaluated
+    once on unbatched scalars, and only the lane-varying part is
+    ``jax.vmap``-ed over the stimulus batch axis.  A memory port whose
+    address is uniform (a *row port*) reads or writes one row of lanes of
+    the memory, held lanes-minor inside the scan, as a dynamic slice in
+    place; a port with a data-dependent address keeps a per-lane gather
+    and scatter.  The NumPy backend runs the whole tape batch-first on
+    the host with a Python cycle loop — still vectorized over stimulus —
+    and serves as the host-side cross-check in ``verify_rtl_passes``.
 
 Semantics follow the event-driven oracle (``lower.to_sim``): values are bit
 patterns masked to their net width, ``Signed`` sign-extends, division is
@@ -81,8 +89,9 @@ def _signed_fix(p: np.ndarray, w: int, signed: bool) -> np.ndarray:
 
 # ---------------------------------------------------------------------------
 # Array-op backends.  The compiled tape is backend-agnostic: every closure
-# takes (env, ops).  _JaxOps values are per-lane scalars (vmap adds the batch
-# axis); _NumpyOps values are batch-first (B,) arrays.
+# takes (env, ops).  _JaxOps values are scalars: lane-uniform ones unbatched,
+# lane-varying ones per lane (vmap adds the batch axis); _NumpyOps values are
+# batch-first (B,) arrays.
 # ---------------------------------------------------------------------------
 
 
@@ -114,6 +123,24 @@ class _JaxOps:
     def write_mem(self, mem, addr, data, enb):
         a = jnp.clip(jnp.asarray(addr, dtype=jnp.int64), 0, mem.shape[0] - 1)
         return mem.at[a].set(jnp.where(enb, data, mem[a]))
+
+    # Row ports: the address is lane-uniform (unbatched under the vmap), so
+    # the batching rules turn these into one dynamic slice / in-place update
+    # of a whole row of lanes instead of a per-lane gather / scatter.
+    @staticmethod
+    def _row(mem, addr):
+        a = jnp.clip(jnp.asarray(addr, dtype=jnp.int64), 0, mem.shape[0] - 1)
+        return a.astype(jnp.int32)
+
+    def read_row(self, mem, addr):
+        return jax.lax.dynamic_index_in_dim(mem, self._row(mem, addr), 0,
+                                            keepdims=False)
+
+    def write_row(self, mem, addr, data, enb):
+        a = self._row(mem, addr)
+        old = jax.lax.dynamic_index_in_dim(mem, a, 0, keepdims=False)
+        new = jnp.asarray(jnp.where(enb, data, old), dtype=mem.dtype)
+        return jax.lax.dynamic_update_index_in_dim(mem, new, a, 0)
 
 
 class _NumpyOps:
@@ -483,6 +510,15 @@ class RTLSimulator:
             comb_nodes.append((nm, "assign", ((lambda env, ops: 0),
                                               _mask_of(w)), ()))
 
+        self.varying = varying = self._lane_varying()
+        # (tag, memory state key, row path?) per memory port
+        self.mem_ports: list[tuple[str, str, bool]] = []
+
+        def row_port(tag: str, memkey: str, addr: Expr) -> bool:
+            row = not any(r in varying for r in addr.refs())
+            self.mem_ports.append((tag, memkey, row))
+            return row
+
         for it in m.items:
             if isinstance(it, CombAssign):
                 fn, _ = _compile_expr(it.expr, widths)
@@ -506,16 +542,20 @@ class RTLSimulator:
                                 _mask_of(widths[it.dest])))
             elif isinstance(it, MemRead):
                 mark_state(it.dest)
+                memkey = f"mem:{it.mem}:{it.bank}"
                 afn, _ = _compile_expr(it.addr, widths)
                 efn, _ = _compile_expr(it.en, widths)
-                clocked.append(("memrd", it.dest, f"mem:{it.mem}:{it.bank}",
-                                afn, efn, _mask_of(widths[it.dest])))
+                clocked.append(("memrd", it.dest, memkey, afn, efn,
+                                _mask_of(widths[it.dest]),
+                                row_port("memrd", memkey, it.addr)))
             elif isinstance(it, MemWrite):
+                memkey = f"mem:{it.mem}:{it.bank}"
                 afn, _ = _compile_expr(it.addr, widths)
                 dfn, _ = _compile_expr(it.data, widths)
                 efn, _ = _compile_expr(it.en, widths)
-                clocked.append(("memwr", f"mem:{it.mem}:{it.bank}", afn, dfn,
-                                efn, _mask_of(mems[it.mem].width)))
+                clocked.append(("memwr", memkey, afn, dfn, efn,
+                                _mask_of(mems[it.mem].width),
+                                row_port("memwr", memkey, it.addr)))
             elif isinstance(it, LoopController):
                 mark_state(it.iv)
                 mark_state(it.active)
@@ -558,6 +598,40 @@ class RTLSimulator:
         self.trace_names = ([p.name for p in m.ports if p.dir == "output"]
                             + list(self._ext_traced))
         self.comb_tape = self._topo_sort(comb_nodes)
+        self.row_ports = sum(row for _t, _k, row in self.mem_ports)
+        self.gather_ports = len(self.mem_ports) - self.row_ports
+        self.uniform_nets = sum(1 for n in widths if n not in varying)
+
+    def _lane_varying(self) -> set[str]:
+        """The nets whose value may differ between lanes: the least set that
+        holds the per-lane sources (scalar inputs, the cells of register-bank
+        arguments, every memory read's data) and every net written by a
+        ``CombAssign``, ``RegAssign``, ``ShiftReg`` or ``LoopController``
+        (all its nets at once) that reads one of them.  Every other net is
+        lane-uniform.  That is sound because every state leaf outside the
+        sources starts at 0 in every lane (``_init_state``), so by induction
+        on the cycle a uniform net computes the same value in every lane;
+        memories, the captured returns and the conflict counts are per-lane
+        state whatever their inputs."""
+        varying = {b.port for b in self.binds if b.kind == "scalar"}
+        varying.update(cn for b in self.binds for row in b.cells
+                       for cn in row)
+        deps: list[tuple[set[str], tuple[str, ...]]] = []
+        for it in self.flat.items:
+            if isinstance(it, MemRead):
+                varying.add(it.dest)
+            elif isinstance(it, (CombAssign, RegAssign, ShiftReg,
+                                 LoopController)):
+                deps.append((set(it.reads()), tuple(it.writes())))
+        changed = True
+        while changed:
+            changed = False
+            for reads, writes in deps:
+                if not varying.issuperset(writes) and not reads.isdisjoint(
+                        varying):
+                    varying.update(writes)
+                    changed = True
+        return varying
 
     @staticmethod
     def _topo_sort(nodes: list[tuple]) -> list[tuple]:
@@ -592,119 +666,238 @@ class RTLSimulator:
         return [nodes[i] for i in order]
 
     # -- the per-cycle step --------------------------------------------------
+    def _trace_ports(self, trace: bool) -> list[str]:
+        return self.trace_names if trace else [
+            p for pair in self.results for p in pair]
+
+    @staticmethod
+    def _load(env, state, scalar_inputs, state_nets, sr_loads, ops) -> None:
+        for pn, key in scalar_inputs:
+            env[pn] = state[key]
+        for n in state_nets:
+            env[n] = state[n]
+        for dest, key in sr_loads:
+            env[dest] = ops.sr_out(state[key])
+
+    @staticmethod
+    def _comb(tape, env, state, ops) -> None:
+        """Evaluate the combinational ``tape`` (in order) into ``env``."""
+        for dest, kind, payload, _reads in tape:
+            if kind == "assign":
+                fn, mk = payload
+                env[dest] = fn(env, ops) & mk
+            else:  # controller iter pulse
+                c = payload
+                act = state[c["active"]]
+                iv = state[c["iv"]]
+                sv = c["start"](env, ops) != 0
+                step_up = iv + c["step"](env, ops)
+                more = step_up < c["ub"](env, ops)
+                if c["ii"] is not None:
+                    cn = (state[c["iicnt"]] == c["ii"] - 1) \
+                        if c["ii"] > 1 else (act == act)
+                else:
+                    cn = c["inner"](env, ops) != 0
+                env[dest] = ops.b2i(sv | ((act != 0) & cn & more))
+
+    @staticmethod
+    def _clock(clocked, env, state, pend, ops) -> None:
+        """Next values of the state the ``clocked`` entries write, into
+        ``pend`` (read-old, write-new; writes to one key chain in order).
+        On the JAX backend row ports take ``read_row``/``write_row``."""
+        rows = isinstance(ops, _JaxOps)
+
+        def cur(k):
+            return pend[k] if k in pend else state[k]
+
+        for ent in clocked:
+            tag = ent[0]
+            if tag == "sr":
+                _t, key, fn, mk = ent
+                pend[key] = ops.sr_push(cur(key), fn(env, ops) & mk)
+            elif tag == "reg":
+                _t, dest, fn, en, mk = ent
+                enb = True if en is None else (en(env, ops) != 0)
+                pend[dest] = ops.where(enb, fn(env, ops) & mk, cur(dest))
+            elif tag == "memrd":
+                _t, dest, memkey, afn, efn, mk, row = ent
+                read = ops.read_row if rows and row else ops.read_mem
+                enb = efn(env, ops) != 0
+                v = read(state[memkey], afn(env, ops)) & mk
+                pend[dest] = ops.where(enb, v, cur(dest))
+            elif tag == "memwr":
+                _t, memkey, afn, dfn, efn, mk, row = ent
+                write = ops.write_row if rows and row else ops.write_mem
+                enb = efn(env, ops) != 0
+                pend[memkey] = write(
+                    cur(memkey), afn(env, ops), dfn(env, ops) & mk, enb)
+            else:  # controller clocked half
+                c = ent[1]
+                act = state[c["active"]]
+                iv = state[c["iv"]]
+                actb = act != 0
+                sv = c["start"](env, ops) != 0
+                lbv = c["lb"](env, ops)
+                stepv = c["step"](env, ops)
+                ubv = c["ub"](env, ops)
+                step_up = iv + stepv
+                more = step_up < ubv
+                if c["ii"] is not None:
+                    if c["ii"] > 1:
+                        iicnt = state[c["iicnt"]]
+                        cn = iicnt == c["ii"] - 1
+                        nxt = ops.where(cn, ops.zero, iicnt + ops.one)
+                        pend[c["iicnt"]] = ops.where(
+                            sv, ops.zero, ops.where(actb, nxt, iicnt))
+                    else:
+                        cn = actb | True  # constant true, array-shaped
+                else:
+                    cn = c["inner"](env, ops) != 0
+                ivm = c["ivmask"]
+                pend[c["iv"]] = ops.where(
+                    sv, lbv & ivm,
+                    ops.where(actb & cn & more, step_up & ivm, iv))
+                pend[c["active"]] = ops.where(
+                    sv, ops.one,
+                    ops.where(actb & cn & (step_up >= ubv), ops.zero, act))
+                if c["endp"]:
+                    pend[c["endp"]] = ops.b2i(actb & cn & (step_up >= ubv))
+
+    def _capture(self, env, state, pend, ops) -> None:
+        """The captured returns and the port-conflict counts, into
+        ``pend``."""
+        for j, (dp, vp) in enumerate(self.results):
+            validb = env[vp] != 0
+            seen = state[f"ret:{j}:seen"]
+            pend[f"ret:{j}:val"] = ops.where(
+                validb & (seen == 0), env[dp], state[f"ret:{j}:val"])
+            pend[f"ret:{j}:seen"] = ops.where(validb, ops.one, seen)
+        if self.asserts:
+            viols = [ops.b2i(sum(ops.b2i(en(env, ops) != 0)
+                                 for en in ens) > 1)
+                     for _bus, ens in self.asserts]
+            stacked = (jnp if ops.__class__ is _JaxOps
+                       else np).stack(viols, axis=-1)
+            pend["cf"] = state["cf"] + stacked
+
     def _make_step(self, ops, trace: bool):
-        comb_tape = self.comb_tape
-        clocked = self.clocked
-        asserts = self.asserts
-        results = self.results
-        scalar_inputs = self.scalar_inputs
-        state_nets = self.state_nets
-        sr_loads = self.sr_loads
-        trace_names = self.trace_names if trace else [
-            p for pair in results for p in pair]
+        """The whole design's step over batch-first state (the NumPy
+        backend: every value is a (B,) array)."""
+        names = self._trace_ports(trace)
 
         def step(state, t_start):
             env: dict[str, Any] = {"t_start": t_start, "clk": 0, "rst": 0}
-            for pn, key in scalar_inputs:
-                env[pn] = state[key]
-            for n in state_nets:
-                env[n] = state[n]
-            for dest, key in sr_loads:
-                env[dest] = ops.sr_out(state[key])
-            for dest, kind, payload, _reads in comb_tape:
-                if kind == "assign":
-                    fn, mk = payload
-                    env[dest] = fn(env, ops) & mk
-                else:  # controller iter pulse
-                    c = payload
-                    act = state[c["active"]]
-                    iv = state[c["iv"]]
-                    sv = c["start"](env, ops) != 0
-                    step_up = iv + c["step"](env, ops)
-                    more = step_up < c["ub"](env, ops)
-                    if c["ii"] is not None:
-                        cn = (state[c["iicnt"]] == c["ii"] - 1) \
-                            if c["ii"] > 1 else (act == act)
-                    else:
-                        cn = c["inner"](env, ops) != 0
-                    env[dest] = ops.b2i(sv | ((act != 0) & cn & more))
+            self._load(env, state, self.scalar_inputs, self.state_nets,
+                       self.sr_loads, ops)
+            self._comb(self.comb_tape, env, state, ops)
             pend: dict[str, Any] = {}
-
-            def cur(k):
-                return pend[k] if k in pend else state[k]
-
-            for ent in clocked:
-                tag = ent[0]
-                if tag == "sr":
-                    _t, key, fn, mk = ent
-                    pend[key] = ops.sr_push(cur(key), fn(env, ops) & mk)
-                elif tag == "reg":
-                    _t, dest, fn, en, mk = ent
-                    enb = True if en is None else (en(env, ops) != 0)
-                    pend[dest] = ops.where(enb, fn(env, ops) & mk, cur(dest))
-                elif tag == "memrd":
-                    _t, dest, memkey, afn, efn, mk = ent
-                    enb = efn(env, ops) != 0
-                    v = ops.read_mem(state[memkey], afn(env, ops)) & mk
-                    pend[dest] = ops.where(enb, v, cur(dest))
-                elif tag == "memwr":
-                    _t, memkey, afn, dfn, efn, mk = ent
-                    enb = efn(env, ops) != 0
-                    pend[memkey] = ops.write_mem(
-                        cur(memkey), afn(env, ops), dfn(env, ops) & mk, enb)
-                else:  # controller clocked half
-                    c = ent[1]
-                    act = state[c["active"]]
-                    iv = state[c["iv"]]
-                    actb = act != 0
-                    sv = c["start"](env, ops) != 0
-                    lbv = c["lb"](env, ops)
-                    stepv = c["step"](env, ops)
-                    ubv = c["ub"](env, ops)
-                    step_up = iv + stepv
-                    more = step_up < ubv
-                    if c["ii"] is not None:
-                        if c["ii"] > 1:
-                            iicnt = state[c["iicnt"]]
-                            cn = iicnt == c["ii"] - 1
-                            nxt = ops.where(cn, ops.zero, iicnt + ops.one)
-                            pend[c["iicnt"]] = ops.where(
-                                sv, ops.zero, ops.where(actb, nxt, iicnt))
-                        else:
-                            cn = actb | True  # constant true, array-shaped
-                    else:
-                        cn = c["inner"](env, ops) != 0
-                    ivm = c["ivmask"]
-                    pend[c["iv"]] = ops.where(
-                        sv, lbv & ivm,
-                        ops.where(actb & cn & more, step_up & ivm, iv))
-                    pend[c["active"]] = ops.where(
-                        sv, ops.one,
-                        ops.where(actb & cn & (step_up >= ubv), ops.zero,
-                                  act))
-                    if c["endp"]:
-                        pend[c["endp"]] = ops.b2i(
-                            actb & cn & (step_up >= ubv))
-            for j, (dp, vp) in enumerate(results):
-                validb = env[vp] != 0
-                seen = state[f"ret:{j}:seen"]
-                pend[f"ret:{j}:val"] = ops.where(
-                    validb & (seen == 0), env[dp], state[f"ret:{j}:val"])
-                pend[f"ret:{j}:seen"] = ops.where(validb, ops.one, seen)
-            if asserts:
-                viols = [ops.b2i(sum(ops.b2i(en(env, ops) != 0)
-                                     for en in ens) > 1)
-                         for _bus, ens in asserts]
-                cf = state["cf"]
-                stacked = (jnp if ops.__class__ is _JaxOps
-                           else np).stack(viols, axis=-1)
-                pend["cf"] = cf + stacked
+            self._clock(self.clocked, env, state, pend, ops)
+            self._capture(env, state, pend, ops)
             ns = dict(state)
             ns.update(pend)
-            outs = tuple(env[p] for p in trace_names)
-            return ns, outs
+            return ns, tuple(env[p] for p in names)
 
-        return step, trace_names
+        return step, names
+
+    def _split(self):
+        """Partition the step by lane-uniformity: ``(uniform, varying)``,
+        each ``(state keys, state nets, shift-register loads, comb tape,
+        clocked entries)``.  Scalar inputs, memories, returns and conflict
+        counts are varying state."""
+        varying = self.varying
+
+        def lanes(ent) -> bool:
+            tag = ent[0]
+            if tag == "sr":
+                return ent[1][len("sr:"):] in varying
+            if tag == "reg":
+                return ent[1] in varying
+            if tag == "ctrl":
+                return ent[1]["iv"] in varying
+            return True  # memory ports
+
+        parts = []
+        for side in (False, True):
+            nets = [n for n in self.state_nets if (n in varying) == side]
+            srs = [(d, k) for d, k in self.sr_loads if (d in varying) == side]
+            parts.append([nets + [k for _d, k in srs], nets, srs,
+                          [nd for nd in self.comb_tape
+                           if (nd[0] in varying) == side],
+                          [e for e in self.clocked if lanes(e) == side]])
+        uniform = set(parts[0][0])
+        parts[1][0] = [k for k in self.state_shape if k not in uniform]
+        return parts
+
+    def scan_program(self, trace: bool = False):
+        """The whole batched run as one traceable function
+        ``(state, t_start_per_cycle) -> (final_state, per_cycle_outputs)``
+        (a ``lax.scan`` over cycles), and the port names of its per-cycle
+        outputs.  State and outputs keep the host layout: (B, ...) leaves,
+        (T, B) outputs.  Trace it under ``jax.enable_x64(True)``: state is
+        int64.
+
+        Each cycle evaluates the lane-uniform nets (outside ``varying``)
+        once, on unbatched scalars, and ``jax.vmap``-s only the
+        lane-varying rest over the lanes, with the uniform values closed
+        over (unbatched).  Uniform state is taken from lane 0 at the start
+        and broadcast back at the end: ``_init_state`` gives it the same
+        value in every lane.  Memories are held lanes-minor,
+        ``(depth, B)``, inside the scan, so a row port reads or writes one
+        contiguous row of lanes in place."""
+        ops = _JaxOps()
+        names = self._trace_ports(trace)
+        (ukeys, unets, usrs, utape, uclocked), \
+            (vkeys, vnets, vsrs, vtape, vclocked) = self._split()
+        varying = self.varying
+        vnames = [p for p in names if p in varying]
+        axes = {k: 1 if k.startswith("mem:") else 0 for k in vkeys}
+
+        def scanner(state, xs):
+            B = next(iter(state.values())).shape[0]
+
+            def step(carry, t_start):
+                ust, vst = carry
+                env: dict[str, Any] = {"t_start": t_start, "clk": 0,
+                                       "rst": 0}
+                self._load(env, ust, (), unets, usrs, ops)
+                self._comb(utape, env, ust, ops)
+                upend: dict[str, Any] = {}
+                self._clock(uclocked, env, ust, upend, ops)
+
+                def lane(vs):
+                    lenv = dict(env)
+                    self._load(lenv, vs, self.scalar_inputs, vnets, vsrs, ops)
+                    self._comb(vtape, lenv, vs, ops)
+                    pend: dict[str, Any] = {}
+                    self._clock(vclocked, lenv, vs, pend, ops)
+                    self._capture(lenv, vs, pend, ops)
+                    return ({k: pend.get(k, v) for k, v in vs.items()},
+                            tuple(lenv[p] for p in vnames))
+
+                nvs, vouts = jax.vmap(lane, in_axes=(axes,),
+                                      out_axes=(axes, 0), axis_size=B)(vst)
+                nus = {k: jnp.asarray(upend.get(k, v), dtype=jnp.int64)
+                       for k, v in ust.items()}
+                by = dict(zip(vnames, vouts))
+                outs = tuple(by[p] if p in by
+                             else jnp.asarray(env[p], dtype=jnp.int64)
+                             for p in names)
+                return (nus, nvs), outs
+
+            ust = {k: state[k][0] for k in ukeys}
+            vst = {k: jnp.swapaxes(state[k], 0, 1) if axes[k] else state[k]
+                   for k in vkeys}
+            (ust, vst), ys = jax.lax.scan(step, (ust, vst), xs)
+            final = {k: jnp.broadcast_to(v[None], (B,) + v.shape)
+                     for k, v in ust.items()}
+            final.update({k: jnp.swapaxes(v, 0, 1) if axes[k] else v
+                          for k, v in vst.items()})
+            ys = tuple(y if p in varying
+                       else jnp.broadcast_to(y[:, None], (y.shape[0], B))
+                       for p, y in zip(names, ys))
+            return final, ys
+
+        return scanner, names
 
     # -- stimulus packing ----------------------------------------------------
     def _layout(self, b: _Bind, arr: np.ndarray) -> np.ndarray:
@@ -788,20 +981,6 @@ class RTLSimulator:
         res.platform, res.compile_s, res.run_s = timing
         return res
 
-    def scan_program(self, trace: bool = False):
-        """The whole batched run as one traceable function
-        ``(state, t_start_per_cycle) -> (final_state, per_cycle_outputs)``
-        (a ``vmap`` over lanes inside a ``lax.scan`` over cycles), and the
-        port names of its per-cycle outputs.  Trace it under
-        ``jax.enable_x64(True)``: state is int64."""
-        step, names = self._make_step(_JaxOps(), trace)
-        vstep = jax.vmap(step, in_axes=(0, None))
-
-        def scanner(state, xs):
-            return jax.lax.scan(vstep, state, xs)
-
-        return scanner, names
-
     def _run_jax(self, state, xs, B: int, trace: bool):
         key = (trace, B, len(xs))
         with jax.enable_x64(True):
@@ -809,6 +988,9 @@ class RTLSimulator:
                 s0 = jax.device_put(state)
                 xs_d = jax.device_put(xs)
                 jax.block_until_ready((s0, xs_d))
+            count("hir.sim.row_ports", self.row_ports)
+            count("hir.sim.gather_ports", self.gather_ports)
+            count("hir.sim.uniform_nets", self.uniform_nets)
             count("hir.sim.leaves_in", len(state) + 1)
             count("hir.sim.bytes_in",
                   sum(a.nbytes for a in state.values()) + xs.nbytes)

@@ -7,7 +7,7 @@ import pytest
 from repro.core import trace
 from repro.core.codegen import sim as rsim
 from repro.core.codegen.verilog import generate_verilog
-from repro.core.gallery import gemm
+from repro.core.gallery import GALLERY, gemm
 from repro.core.passes import DEFAULT_PIPELINE_SPEC, PassManager
 
 SIM_SPANS = ["hir.sim.run", "hir.sim.layout", "hir.sim.put",
@@ -165,6 +165,26 @@ def test_unbatched_and_numpy_runs_open_one_run_span(gemm4):
     assert tot["hir.sim.run"]["n"] == tot["hir.sim.scan"]["n"] == 1
     assert "hir.sim.put" not in tot and not rec.counters
     assert res.run_s == tot["hir.sim.scan"]["s"]
+
+
+@pytest.mark.parametrize("kernel,kw,rows,gathers", [
+    ("histogram", {"n": 8, "bins": 4}, 4, 2), ("gemm", {"n": 4}, 19, 0)])
+def test_each_jax_run_counts_its_port_paths_and_uniform_nets(kernel, kw,
+                                                             rows, gathers):
+    gal = GALLERY[kernel]
+    mod, entry = gal.build(**kw)
+    batch = rsim.stack_stimulus(gal.make_inputs, 4, **kw)
+    sim, prepared = rsim.simulator_for(mod, entry)
+    cycles = rsim.probe_cycles(prepared, entry,
+                               [np.asarray(a)[0] for a in batch])
+    uniform = sum(1 for n in sim.widths if n not in sim.varying)
+    assert 0 < uniform < len(sim.widths)
+    for _ in range(2):  # once per run, compiled or not
+        with trace.record() as rec:
+            sim.run(batch, cycles, batched=True)
+        c = rec.counters
+        assert (c["hir.sim.row_ports"], c["hir.sim.gather_ports"],
+                c["hir.sim.uniform_nets"]) == (rows, gathers, uniform)
 
 
 def test_run_differential_spans(gemm4):

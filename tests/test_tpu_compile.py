@@ -74,6 +74,32 @@ def test_simulator_scan_compiles(one_chip, kernel, kw):
                                              for v in state.values())
 
 
+@pytest.mark.parametrize("kernel,kw,per_lane", [
+    ("conv2d", {"h": 6, "w": 6}, False),
+    ("histogram", {"n": 8, "bins": 4}, True)])
+def test_simulator_row_ports_compile_to_slices(one_chip, kernel, kw,
+                                               per_lane):
+    """On the chip too, a memory port with a lane-uniform address is a
+    dynamic slice of a row of lanes; only data-dependent addresses (the
+    histogram's bins) keep a per-lane gather and scatter."""
+    gal = GALLERY[kernel]
+    module, entry = gal.build(**kw)
+    sim, prepared = rsim.simulator_for(module, entry)
+    lanes = 256
+    batch = rsim.stack_stimulus(gal.make_inputs, lanes, **kw)
+    cycles = rsim.probe_cycles(prepared, entry, [a[0] for a in batch])
+    with jax.enable_x64(True):
+        state = sim._init_state(batch, lanes)
+        scanner, _names = sim.scan_program()
+        text = jax.jit(scanner).lower(
+            {k: _spec(v, one_chip) for k, v in state.items()},
+            _spec(np.zeros(cycles, np.int64), one_chip)).compile().as_text()
+    per_lane_ops = [op for op in (" gather(", " scatter(") if op in text]
+    assert bool(per_lane_ops) == per_lane, per_lane_ops
+    assert (sim.gather_ports > 0) == per_lane
+    assert " dynamic-update-slice(" in text
+
+
 @pytest.mark.parametrize("kernel", ["array_add", "stencil1d", "conv2d"])
 def test_pallas_binding_compiles(one_chip, kernel):
     gal = GALLERY[kernel]
