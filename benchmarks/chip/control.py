@@ -6,9 +6,12 @@
 
 The control is the plain reference put in the program's place and computed
 in int16, the nearest integer precision below the configurations' int32
-datapath.  The cell runs as usual; after each ``RTLSimulator.run`` of the
-timed path, the output memref it returns is replaced by the reference's
-int16 answer for the same stimulus.  One JSON line per seed gives
+datapath.  A configuration whose answers int16 cannot change (a histogram
+never counts past 2^15) gets its wrong answer from its reference module's
+own ``control(config, args)`` instead: the answer the design's realistic
+fault would give.  The cell runs as usual; after each ``RTLSimulator.run``
+of the timed path, the output memref it returns is replaced by the
+control's answer for the same stimulus.  One JSON line per seed gives
 ``correct`` and each number compared with its limit; every line must read
 ``"correct": false``, and the smallest reading of each number is its upper
 reading in ``PERF.md``.  The benchmark's own runs never run this.
@@ -38,8 +41,14 @@ CONTROL_DTYPE = np.int16
 @contextlib.contextmanager
 def reference_in_place(config: dict, reference, dtype=CONTROL_DTYPE):
     """Within the block, every batched ``RTLSimulator.run`` returns the
-    reference's answer in ``dtype`` as its output memref."""
+    control's answer as its output memref: ``reference.control``'s where
+    the module defines it, else the reference's in ``dtype``."""
     from repro.core.codegen import sim as rsim
+
+    def answer(domain):
+        if hasattr(reference, "control"):
+            return reference.control(config, domain)
+        return reference.reference(config, domain, dtype=dtype)
 
     orig = rsim.RTLSimulator.run
 
@@ -47,8 +56,7 @@ def reference_in_place(config: dict, reference, dtype=CONTROL_DTYPE):
         res = orig(self, args, cycles, batched=batched, **kw)
         domain = stimulus.domain_args(config["inputs"],
                                       [np.asarray(a) for a in args])
-        res.arrays[config["output_arg"]] = reference.reference(
-            config, domain, dtype=dtype)
+        res.arrays[config["output_arg"]] = answer(domain)
         return res
 
     rsim.RTLSimulator.run = run

@@ -2,9 +2,10 @@
 
 The harness (``benchmarks/chip``) is put on ``sys.path``.  ``tiny_layout``
 gives a copy of ``BENCHMARK.json`` in a temporary directory whose
-configurations are cut to CPU test size and whose DSE traffic lists two
-variants; the harness finds those files before the real ones.  Runs skip
-the look for a chip, and leave JAX's compilation cache settings alone.
+configurations are cut to the CPU test size each gives under
+``cpu_test_size`` and whose DSE traffic lists two variants; the harness
+finds those files before the real ones.  Runs skip the look for a chip,
+and leave JAX's compilation cache settings alone.
 """
 
 import json
@@ -21,12 +22,6 @@ if str(BENCH) not in sys.path:
 
 import harness  # noqa: E402
 
-#: test sizes: build arguments and argument shapes
-TINY = {
-    "gemm16": ({"n": 4}, {"A": [4, 4], "B": [4, 4], "C": [4, 4]}),
-    "conv2d128x64": ({"h": 8, "w": 8}, {"Img": [8, 8], "Out": [6, 6]}),
-    "conv2d16x64": ({"h": 6, "w": 8}, {"Img": [6, 8], "Out": [4, 6]}),
-}
 SEED = 2**31 + 7
 
 
@@ -42,24 +37,42 @@ def write_json(path: Path, obj) -> None:
     path.write_text(json.dumps(obj, indent=1))
 
 
+def tiny_copy(root: Path, dest: Path) -> harness.Layout:
+    """The benchmark of the checkout ``root`` at CPU test size, in ``dest``.
+
+    Each configuration's build arguments and input shapes are replaced by
+    its ``cpu_test_size`` (``{"build": {...}, "shapes": {arg: shape}}``),
+    which no runner reads; every traffic mix of the ``dse`` runner keeps
+    two variants on 16 lanes.  Files not cut are found under ``root``'s
+    ``benchmarks/chip``, then the real one's."""
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    for c in spec["configs"]:
+        cfg = json.loads((root / c["file"]).read_text())
+        if "cpu_test_size" not in cfg:
+            raise ValueError(f"configuration {c['name']!r} ({c['file']}) "
+                             "gives no cpu_test_size")
+        size = cfg["cpu_test_size"]
+        cfg["build"] = size["build"]
+        for arg in cfg["inputs"]:
+            arg["shape"] = size["shapes"][arg["name"]]
+        c["file"] = f"configs/{c['name']}.json"
+        write_json(dest / c["file"], cfg)
+    layout = harness.Layout(root=dest, search=list(dict.fromkeys(
+        [dest, root / "benchmarks" / "chip", BENCH])))
+    for w in spec["workloads"]:
+        traffic = json.loads(layout.find("traffic", w["traffic"], ".json")
+                             .read_text())
+        if traffic["runner"] == "dse":
+            traffic["variants"] = traffic["variants"][:2]
+            traffic["lanes"] = 16
+            write_json(dest / "traffic" / f"{w['traffic']}.json", traffic)
+    write_json(dest / "BENCHMARK.json", spec)
+    return layout
+
+
 @pytest.fixture
 def tiny_layout(tmp_path) -> harness.Layout:
-    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
-    for c in spec["configs"]:
-        cfg = json.loads((ROOT / c["file"]).read_text())
-        build, shapes = TINY[c["name"]]
-        cfg["build"] = build
-        for arg in cfg["inputs"]:
-            arg["shape"] = shapes[arg["name"]]
-        c["file"] = f"configs/{c['name']}.json"
-        write_json(tmp_path / c["file"], cfg)
-    dse = json.loads((BENCH / "traffic" / "conv2d16x64_variants.json")
-                     .read_text())
-    dse["variants"] = dse["variants"][:2]
-    dse["lanes"] = 16
-    write_json(tmp_path / "traffic" / "conv2d16x64_variants.json", dse)
-    write_json(tmp_path / "BENCHMARK.json", spec)
-    return harness.Layout(root=tmp_path, search=[tmp_path, BENCH])
+    return tiny_copy(ROOT, tmp_path)
 
 
 @pytest.fixture

@@ -15,9 +15,11 @@ import xplane
 from repro.core.gallery import GALLERY
 
 
+SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
+
+
 def _config(name):
-    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
-    entry = {c["name"]: c for c in spec["configs"]}[name]
+    entry = {c["name"]: c for c in SPEC["configs"]}[name]
     return json.loads((ROOT / entry["file"]).read_text())
 
 
@@ -26,16 +28,20 @@ def _reference(config):
                                / f"{config['reference']}.py")
 
 
-@pytest.mark.parametrize("name,oracle_args", [("gemm16", 2),
-                                              ("conv2d128x64", 1)])
+@pytest.mark.parametrize("name,oracle_args", [
+    (c["name"], sum("fill" not in arg for arg in _config(c["name"])["inputs"]))
+    for c in SPEC["configs"]])
 def test_reference_matches_gallery_oracle(name, oracle_args):
+    """Every configuration's reference against its design's gallery
+    oracle, which takes the configuration's drawn inputs."""
     config = _config(name)
     gal = GALLERY[config["design"]]
-    args = stimulus.batch(config["inputs"], 8, 12345, 0)
-    got = _reference(config).reference(
-        config, stimulus.domain_args(config["inputs"], args))
+    args = stimulus.domain_args(
+        config["inputs"], stimulus.batch(config["inputs"], 8, 12345, 0))
+    assert len(args) == oracle_args
+    got = _reference(config).reference(config, args)
     for lane in range(8):
-        want = gal.oracle(*[a[lane] for a in args[:oracle_args]])
+        want = gal.oracle(*[a[lane] for a in args])
         np.testing.assert_array_equal(got[lane], want)
 
 

@@ -1,12 +1,13 @@
 """The benchmark's cells at CPU test size, run through the harness.
 
-Each run skips only the look for a chip: set-up, the window, the check
-against the plain reference and the metric readers are the benchmark's own.
-Then the timed path is broken underneath, once for each fault a
-one-chip verification cell can have, and ``correct`` must read false; the
-control (the reference in int16 in the program's place) must too.  A
-dummy configuration, traffic mix, reference and metric, added as new files
-and entries only, are found and run.
+The cells are those of ``BENCHMARK.json``.  Each run skips only the look
+for a chip: set-up, the window, the check against the plain reference and
+the metric readers are the benchmark's own.  Then the timed path is broken
+underneath, once for each fault a one-chip verification cell can have
+(``cell_checks.FAULTS``), and ``correct`` must read false; the control (the
+configuration's wrong answer in the program's place) must too.  A dummy
+configuration, traffic mix, reference and metric, added as new files and
+entries only, are found and run.
 """
 
 import json
@@ -14,34 +15,20 @@ import os
 import subprocess
 import sys
 
-import numpy as np
 import pytest
+from cell_checks import FAULTS, check_correct, check_not_correct
 from conftest import BENCH, ROOT, write_json
 
 import control
 import harness
-from repro.core.codegen import sim as rsim
 
-CELLS = ["gemm16.bulk", "conv2d128x64.bulk", "conv2d16x64.dse"]
-#: each cell's end-to-end metric besides ``setup_s``
-E2E = {"gemm16.bulk": "verify_vcps",
-       "conv2d128x64.bulk": "verify_vcps.memory",
-       "conv2d16x64.dse": "design_s"}
+SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
+CELLS = [w["name"] for w in SPEC["workloads"]]
 
 
 @pytest.mark.parametrize("workload", CELLS)
 def test_cell_is_correct(tiny_layout, run_cell, workload):
-    r = run_cell(tiny_layout, workload)
-    assert r["correct"], r["checks"]
-    assert r["failed"] == 0 and r["attempted"] > 0
-    assert set(r["metrics"]) == {"setup_s", E2E[workload]}
-    assert all(v["value"] > 0 for v in r["metrics"].values())
-    assert list(r)[-1] == "checks"
-    assert all(c["limit"] == 0 for c in r["checks"].values())
-    phases = r["setup_phases"]
-    assert "init_s" in phases and "warmup_s" in phases
-    assert (phases["init_s"] + phases["warmup_s"]
-            <= r["metrics"]["setup_s"]["value"])
+    check_correct(run_cell(tiny_layout, workload), SPEC, workload)
 
 
 def test_bulk_window_counts_whole_batches(tiny_layout, run_cell):
@@ -79,58 +66,12 @@ def test_dse_traces_its_fixed_variants(tiny_layout, monkeypatch):
     assert (attempted, failed) == (2, 0)
 
 
-def _state_unchanged(monkeypatch):
-    orig = rsim.RTLSimulator.scan_program
-
-    def scan_program(self, trace=False):
-        scanner, names = orig(self, trace)
-
-        def frozen(state, xs):
-            _, ys = scanner(state, xs)
-            return state, ys
-
-        return frozen, names
-
-    monkeypatch.setattr(rsim.RTLSimulator, "scan_program", scan_program)
-
-
-def _half_batch(monkeypatch):
-    orig = rsim.RTLSimulator.run
-
-    def run(self, args, cycles, batched=False, **kw):
-        lanes = np.asarray(args[0]).shape[0]
-        half = (lanes + 1) // 2
-        res = orig(self, [np.asarray(a)[:half] for a in args], cycles,
-                   batched=batched, **kw)
-        for k, a in res.arrays.items():
-            res.arrays[k] = np.concatenate([a, a[:lanes - half]])
-        return res
-
-    monkeypatch.setattr(rsim.RTLSimulator, "run", run)
-
-
-def _altered_answer(monkeypatch):
-    orig = rsim.RTLSimulator._collect
-
-    def collect(self, *a, **kw):
-        res = orig(self, *a, **kw)
-        out = res.arrays[max(res.arrays)]
-        out.reshape(out.shape[0], -1)[-1, -1] ^= 1
-        return res
-
-    monkeypatch.setattr(rsim.RTLSimulator, "_collect", collect)
-
-
 @pytest.mark.parametrize("workload", CELLS)
-@pytest.mark.parametrize("fault", [_state_unchanged, _half_batch,
-                                   _altered_answer])
+@pytest.mark.parametrize("fault", FAULTS)
 def test_fault_in_timed_path_is_not_correct(tiny_layout, run_cell,
                                             monkeypatch, workload, fault):
     fault(monkeypatch)
-    r = run_cell(tiny_layout, workload)
-    assert not r["correct"]
-    assert r["failed"] > 0
-    assert r["checks"]["mismatched_lanes"]["value"] > 0
+    check_not_correct(run_cell(tiny_layout, workload))
 
 
 @pytest.mark.parametrize("workload", CELLS)
@@ -138,8 +79,7 @@ def test_control_is_not_correct(tiny_layout, workload):
     results = control.run_control(tiny_layout, workload, [1, 2], 0.0,
                                   require_chip=False)
     for r in results:
-        assert not r["correct"]
-        assert r["checks"]["mismatched_lanes"]["value"] > 0
+        check_not_correct(r)
 
 
 def test_new_files_and_entries_only(tiny_layout, run_cell):
