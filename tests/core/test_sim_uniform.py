@@ -77,8 +77,9 @@ def test_every_port_takes_the_row_path(kernel, kw):
     assert 0 < sim.uniform_nets < len(sim.widths)
 
 
-def test_histogram_bin_ports_take_the_gather_path():
-    mod, entry = histogram.build(n=8, bins=4)
+@pytest.mark.parametrize("n,bins", [(8, 4), (8192, 256)])
+def test_histogram_bin_ports_take_the_gather_path(n, bins):
+    mod, entry = histogram.build(n=n, bins=bins)
     sim, _ = rsim.simulator_for(mod, entry)
     assert (sim.row_ports, sim.gather_ports) == (4, 2)
     gathered = {(t, k) for t, k, row in sim.mem_ports if not row}
@@ -89,6 +90,28 @@ def test_histogram_bin_ports_take_the_gather_path():
     # a port is a row port exactly when its address reads no varying net
     for it, (_t, _k, row) in zip(_mem_items(sim), sim.mem_ports):
         assert row == sim.varying.isdisjoint(it.addr.refs())
+
+
+def test_histogram_at_published_widths_counts_past_eight_bits():
+    """One 8-bit CLAHE tile of 8,192 pixels into 256 bins, through the JAX
+    scan's per-lane gather and scatter.  Uniform tiles keep every bin near
+    32; a constant tile fills one bin to 8,192 and a dark tile puts most
+    pixels in a few bins, so state or a bin memory narrowed below 14 bits
+    would wrap.  The II=2 main loop fixes the cycle count."""
+    mod, entry = histogram.build(n=8192, bins=256)
+    sim, prepared = rsim.simulator_for(mod, entry)
+    rng = np.random.default_rng(16)
+    img = rng.integers(0, 256, size=(4, 8192))
+    img[0] = 200
+    img[1] = np.minimum(rng.geometric(1 / 8, size=8192) - 1, 255)
+    img[2, :6000] = 255
+    out = np.zeros((4, 256), dtype=np.int64)
+    cycles = rsim.probe_cycles(prepared, entry, [img[0], out[0]])
+    assert cycles == 16916
+    got = sim.run([img, out], cycles, batched=True).arrays[1]
+    want = np.stack([np.bincount(tile, minlength=256) for tile in img])
+    assert want[0, 200] == 8192 and want[1].max() > 2 ** 9
+    np.testing.assert_array_equal(got, want)
 
 
 def test_scalar_loop_bound_makes_its_controller_lane_varying():
